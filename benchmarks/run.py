@@ -47,6 +47,8 @@ def main(argv=None) -> None:
                     help="enable I/O tracing and write a Chrome trace_event "
                          "JSON (load in https://ui.perfetto.dev)")
     args = ap.parse_args(argv)
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     wanted = None if args.suites is None else {
         s.strip() for s in args.suites.split(",") if s.strip()}

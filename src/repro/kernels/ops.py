@@ -1,8 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) kernels run in interpret mode automatically; on TPU
-they compile natively.  ``ref.py`` holds the pure-jnp oracles used by the
-per-kernel allclose sweeps in tests/test_kernels.py.
+On the CPU backend the kernels run in interpret mode; on any other backend
+they lower through Mosaic.  Only the field codec is checked on the TPU:
+``tests/test_tpu_compile.py`` compiles it for a described v5e chip and
+``chip_smoke.py`` runs it on one.  ``ref.py`` holds the pure-jnp oracles
+used by the per-kernel allclose sweeps in tests/test_kernels.py.
 """
 from __future__ import annotations
 

@@ -14,6 +14,7 @@ import numpy as np
 
 from repro.configs import get_config, get_smoke_config
 from repro.core import FDBConfig
+from repro.launch.cache import enable_compile_cache
 from repro.models import lm
 from repro.serve import Request, ServeEngine
 from repro.train.checkpoint import FDBCheckpointer
@@ -31,6 +32,7 @@ def main() -> None:
                    help="restore weights from this FDB checkpoint run")
     p.add_argument("--backend", default="daos")
     args = p.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     params = lm.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from repro.configs import get_config, get_smoke_config
 from repro.core import FDBConfig
 from repro.data import SyntheticTokens
+from repro.launch.cache import enable_compile_cache
 from repro.train.checkpoint import FDBCheckpointer
 from repro.train.optimizer import AdamWConfig
 from repro.train.trainer import Trainer, run_with_restarts
@@ -39,6 +40,7 @@ def main() -> None:
     p.add_argument("--async-ckpt", action="store_true")
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     data = SyntheticTokens(cfg.vocab_size, args.seq, seed=args.seed)

@@ -49,7 +49,6 @@ class MeshPlan:
     fsdp: bool = False                  # shard params over data on embed dim
     sp: bool = False                    # sequence-parallel residuals
     remat: bool = True
-    grad_compress_pod: bool = False     # field-codec gradient compression
     extra: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
     @property

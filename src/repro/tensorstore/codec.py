@@ -1,9 +1,11 @@
 """Per-chunk codecs: raw passthrough + the Pallas field codec.
 
 ``field8``/``field16`` reuse the TPU field-packing kernels
-(:mod:`repro.kernels.field_codec`): the chunk is flattened, the lane-aligned
-head (a multiple of 128 elements) is block-quantised to int8/int16 with
-per-block (scale, min) pairs, and the sub-lane tail rides along as float32.
+(:mod:`repro.kernels.field_codec`): the chunk is flattened, its head of
+128-element rows is block-quantised to int8/int16 with per-block (scale,
+min) pairs, and the rest rides along as float32 — the sub-lane tail, plus,
+in chunks longer than one block, the rows past the last multiple of the
+chip's row tile (see :meth:`FieldQuantCodec._layout`).
 Chunks that cannot profit (non-float dtypes, tiny chunks) fall back to raw
 bytes — the one-byte container header makes every chunk self-describing, so
 edge chunks of any shape roundtrip exactly through either path.
@@ -21,6 +23,10 @@ Container layout (little-endian):
   quantised payload:
   [1:9] rows:u32, block:u32
   [9:]  q (rows*128 int8|int16) | scale (rows/block f32) | mins (f32) | tail f32
+
+The header makes every container self-describing: containers written
+before blocks were held to the chip's tiling (blocks of 1, 2 or 4 rows)
+still decode, through the jnp reference decoder on the same device.
 """
 from __future__ import annotations
 
@@ -31,7 +37,7 @@ import numpy as np
 
 _LANES = 128
 _RAW, _QUANT = 0, 1
-_BLOCK_CANDIDATES = (256, 128, 64, 32, 16, 8, 4, 2, 1)
+_BLOCK_CANDIDATES = (256, 128, 64, 32, 16, 8)
 
 
 class Codec:
@@ -84,13 +90,21 @@ class FieldQuantCodec(Codec):
 
     @staticmethod
     def _layout(size: int) -> Tuple[int, int, int]:
-        """(lane-aligned head length, quantised rows, block) for a chunk of
-        ``size`` elements — shared by the loop and batched encode paths so
-        both pick identical quantisation geometry."""
-        n = (size // _LANES) * _LANES
-        rows = n // _LANES
-        block = next(b for b in _BLOCK_CANDIDATES if rows % b == 0)
-        return n, rows, block
+        """(quantised head length, quantised rows, block) for a chunk of
+        ``size`` elements — shared by the loop and batched encode paths
+        (and the legacy checkpoint blobs) so all pick identical geometry.
+
+        The block is the largest candidate dividing the rows, so it is a
+        multiple of the chip's row tile (``field_codec.SUBLANES``); a chunk
+        of at most 256 rows that none divides is one block.  A longer chunk
+        quantises its rows down to a multiple of the tile, and the rows
+        past it join the float32 tail."""
+        from repro.kernels.field_codec import SUBLANES
+        rows = size // _LANES
+        if rows > _BLOCK_CANDIDATES[0]:
+            rows -= rows % SUBLANES
+        block = next((b for b in _BLOCK_CANDIDATES if rows % b == 0), rows)
+        return rows * _LANES, rows, block
 
     def _container(self, rows: int, block: int, q, scale, mins,
                    tail: np.ndarray) -> bytes:
@@ -143,6 +157,34 @@ class FieldQuantCodec(Codec):
                                          mins[k], flats[k][n:])
         return out
 
+    def _decode_head(self, q: np.ndarray, scale: np.ndarray,
+                     mins: np.ndarray, block: int):
+        """Decode quantised rows q (rows, 128) or (B, rows, 128) on the
+        device: through the kernel, or — for a container whose block the
+        chip's tiling refuses — through the jnp reference decoder."""
+        import jax.numpy as jnp
+
+        from repro.kernels import ops, ref
+        from repro.kernels.field_codec import legal_block
+        if legal_block(q.shape[-2], block):
+            return ops.field_decode(q, scale, mins, block=block,
+                                    bits=self.bits)
+        return ref.field_decode_ref(
+            jnp.asarray(q.reshape(-1, _LANES)), jnp.asarray(scale.reshape(-1)),
+            jnp.asarray(mins.reshape(-1)), block=block,
+            bits=self.bits).reshape(q.shape)
+
+    def describes(self, data: bytes, size: int) -> bool:
+        """Whether ``data`` is a quantised container of a ``size``-element
+        chunk: a header whose (rows, block) agree with its length."""
+        if len(data) < 9 or data[0] != _QUANT:
+            return False
+        rows, block = struct.unpack_from("<II", data, 1)
+        n = rows * _LANES
+        return (block > 0 and rows % block == 0 and n <= size
+                and len(data) == 9 + n * np.dtype(self._qdtype).itemsize
+                + 8 * (rows // block) + 4 * (size - n))
+
     def _parse(self, data: bytes):
         """Split a quantised container into its typed views (zero-copy)."""
         rows, block = struct.unpack_from("<II", data, 1)
@@ -163,10 +205,8 @@ class FieldQuantCodec(Codec):
         if data[0] == _RAW:
             return np.frombuffer(data, dtype=dtype, offset=1
                                  ).reshape(shape).copy()
-        from repro.kernels import ops
         _rows, block, q, scale, mins, tail = self._parse(data)
-        head = np.asarray(ops.field_decode(q, scale, mins, block=block,
-                                           bits=self.bits))
+        head = np.asarray(self._decode_head(q, scale, mins, block))
         return np.concatenate([head.reshape(-1), tail]).astype(
             dtype, copy=False).reshape(shape)
 
@@ -184,15 +224,12 @@ class FieldQuantCodec(Codec):
             else:
                 rows, block = struct.unpack_from("<II", d, 1)
                 groups.setdefault((tuple(s), rows, block), []).append(i)
-        if groups:
-            from repro.kernels import ops
         for (shape, rows, block), idxs in groups.items():
             parsed = [self._parse(datas[i]) for i in idxs]
-            heads = np.asarray(ops.field_decode(
+            heads = np.asarray(self._decode_head(
                 np.stack([p[2] for p in parsed]),
                 np.stack([p[3] for p in parsed]),
-                np.stack([p[4] for p in parsed]),
-                block=block, bits=self.bits))
+                np.stack([p[4] for p in parsed]), block))
             for k, i in enumerate(idxs):
                 out[i] = np.concatenate(
                     [heads[k].reshape(-1), parsed[k][5]]).astype(

@@ -65,6 +65,9 @@ import numpy as np
 from repro.core import FDB, FDBConfig, Identifier
 from repro.core.schema import CHECKPOINT_SCHEMA
 from repro.tensorstore import ChunkedArray, TensorStore, auto_chunks
+from repro.tensorstore.codec import FieldQuantCodec
+
+_FIELD8 = FieldQuantCodec(8)
 
 
 def _tensor_name(path) -> str:
@@ -183,43 +186,33 @@ class FDBCheckpointer:
                  for si, shard in enumerate(shards)])
 
     def _compress(self, arr: np.ndarray) -> np.ndarray:
-        from repro.kernels import ops
-        flat = arr.reshape(-1)
-        c = 128
-        n = (flat.size // c) * c
-        if n == 0:
-            return arr
-        head = flat[:n].reshape(-1, c)
-        rows = head.shape[0]
-        block = next(b for b in (256, 128, 64, 32, 16, 8, 4, 2, 1)
-                     if rows % b == 0)
-        q, s, m = ops.field_encode(head, block=block)
-        # store quantised ints + scales in one buffer (simple container)
-        out = np.concatenate([
-            np.asarray(q, np.int8).reshape(-1).view(np.uint8),
-            np.asarray(s, np.float32).view(np.uint8).reshape(-1),
-            np.asarray(m, np.float32).view(np.uint8).reshape(-1),
-            flat[n:].astype(np.float32).view(np.uint8).reshape(-1),
-        ]).astype(np.uint8)
-        return out
+        """Legacy shard-blob quantiser: the blob is a ``field8`` chunk
+        container, so it names its own (rows, block) geometry."""
+        return np.frombuffer(_FIELD8.encode(arr), np.uint8)
 
     def _decompress(self, buf: np.ndarray, ref: np.ndarray) -> np.ndarray:
-        from repro.kernels import ops
+        """Decode a legacy shard blob: a ``field8`` container, or a
+        headerless blob of older runs, whose geometry follows from its size
+        under the block rule it was written with (blocks down to 1 row).
+        A container's size is 1 + 4*size (mod 8) and a headerless blob's
+        4*size (mod 8), so the two can never be mistaken for each other."""
         size = ref.size
-        c = 128
-        n = (size // c) * c
-        rows = n // c
+        data = buf.tobytes()
+        if _FIELD8.describes(data, size):
+            return _FIELD8.decode(data, (size,), np.dtype(np.float32))
+        rows = size // 128
         block = next(b for b in (256, 128, 64, 32, 16, 8, 4, 2, 1)
-                     if rows % b == 0) if rows else 1
-        nb = rows // block if rows else 0
-        q = buf[:n].view(np.int8).reshape(rows, c)
-        off = n
-        s = buf[off:off + 4 * nb].view(np.float32)
-        off += 4 * nb
-        m = buf[off:off + 4 * nb].view(np.float32)
-        off += 4 * nb
-        tail = buf[off:].view(np.float32)
-        head = np.asarray(ops.field_decode(q, s, m, block=block))
+                     if rows % b == 0)
+        n, nb = rows * 128, rows // block
+        if buf.size != n + 8 * nb + 4 * (size - n):
+            raise ValueError(f"compressed blob of {buf.size} bytes is neither "
+                             f"a field8 container nor a headerless blob of "
+                             f"a {size}-element tensor")
+        q = buf[:n].view(np.int8).reshape(rows, 128)
+        s = buf[n:n + 4 * nb].view(np.float32)
+        m = buf[n + 4 * nb:n + 8 * nb].view(np.float32)
+        tail = buf[n + 8 * nb:].view(np.float32)
+        head = np.asarray(_FIELD8._decode_head(q, s, m, block))
         return np.concatenate([head.reshape(-1), tail]).astype(np.float32)
 
     def save_sharded(self, step: int, params, opt_state=None,

@@ -1,4 +1,6 @@
+import importlib.util
 import os
+from pathlib import Path
 
 import pytest
 
@@ -99,6 +101,17 @@ def protocol_check(request, fresh_engines):
         GLOBAL_TRACER.enable()
     with protocol_guard(GLOBAL_TRACER):
         yield
+
+
+@pytest.fixture(scope="session")
+def smoke():
+    """The root ``chip_smoke.py`` as a module: its phases, and the numpy
+    block quantiser and codec compile check the tests share with it."""
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", Path(__file__).resolve().parents[1] / "chip_smoke.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 @pytest.fixture

@@ -47,12 +47,13 @@ def test_checkpoint_roundtrip_async(tiny_setup):
     ck.close()
 
 
-def test_checkpoint_compressed_roundtrip(tiny_setup):
+@pytest.mark.parametrize("chunked", [True, False])
+def test_checkpoint_compressed_roundtrip(tiny_setup, chunked):
     cfg, _ = tiny_setup
     from repro.models import lm
     params = lm.init_params(cfg, jax.random.PRNGKey(1))
     ck = FDBCheckpointer("comp-run", FDBConfig(backend="daos"),
-                         compress=True)
+                         compress=True, chunked=chunked)
     ck.save(1, params)
     restored = ck.restore(1, params)
     for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(restored)):
@@ -62,6 +63,61 @@ def test_checkpoint_compressed_roundtrip(tiny_setup):
             assert np.abs(a - b).max() <= rng / 255 * 0.51 + 1e-6
         else:
             np.testing.assert_array_equal(a, b)
+    ck.close()
+
+
+def _headerless_blob(arr: np.ndarray, block: int) -> np.ndarray:
+    """A compressed shard blob as older runs wrote it: int8 codes, scales,
+    mins and the float32 tail, with no header."""
+    from repro.kernels import ref
+    flat = arr.reshape(-1)
+    n = flat.size // 128 * 128
+    q, s, m = ref.field_encode_ref(jnp.asarray(flat[:n]).reshape(-1, 128),
+                                   block=block)
+    return np.concatenate([
+        np.asarray(q, np.int8).reshape(-1).view(np.uint8),
+        np.asarray(s, np.float32).view(np.uint8),
+        np.asarray(m, np.float32).view(np.uint8),
+        flat[n:].astype(np.float32).view(np.uint8)])
+
+
+@pytest.mark.parametrize("rows,block", [(84, 4), (383, 1), (766, 2),
+                                        (1532, 4), (2048, 256)])
+def test_headerless_compressed_blob_restores(monkeypatch, rows, block):
+    """Blobs of older runs restore by the block rule they were written
+    with, including sizes where a container of today's layout would have
+    the same number of bytes without its header (383 x 128: 383 rows of
+    block 1, or 376 rows of block 8 plus an 896-value tail)."""
+    x = np.random.default_rng(rows).normal(250, 20, (rows, 128)).astype(
+        np.float32)
+    params = {"w": x}
+    ck = FDBCheckpointer("legacy-run", FDBConfig(backend="daos"),
+                         compress=True, chunked=False)
+    monkeypatch.setattr(ck, "_compress", lambda a: _headerless_blob(a, block))
+    ck.save(1, params)
+    got = np.asarray(ck.restore(1, params)["w"])
+    ck.close()
+    from repro.kernels import ref
+    q, s, m = ref.field_encode_ref(jnp.asarray(x), block=block)
+    expect = np.asarray(ref.field_decode_ref(q, s, m, block=block))
+    np.testing.assert_allclose(got, expect, rtol=1e-6)
+    bound = np.asarray(ref.codec_error_bound(jnp.asarray(x), block))
+    err = np.abs(got - x).reshape(rows // block, -1).max(axis=1)
+    assert (err <= bound + np.abs(x).max() * 1e-6).all()
+
+
+def test_compressed_blob_of_unknown_size_is_refused():
+    """A blob that is neither a field8 container nor a headerless blob of
+    the tensor's size raises instead of decoding to wrong values."""
+    ck = FDBCheckpointer("legacy-run", FDBConfig(backend="daos"),
+                         chunked=False)
+    ref = np.zeros((383, 128), np.float32)
+    blob = ck._compress(ref + np.arange(128, dtype=np.float32))
+    assert blob[0] == 1 and ck._decompress(blob, ref).shape == (ref.size,)
+    with pytest.raises(ValueError, match="neither"):
+        ck._decompress(blob[:-4], ref)
+    with pytest.raises(ValueError, match="neither"):
+        ck._decompress(_headerless_blob(ref, 1)[:-8], ref)
     ck.close()
 
 
