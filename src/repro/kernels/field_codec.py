@@ -115,6 +115,7 @@ def field_encode(x: jax.Array, block: int = 256, bits: int = 8,
         out_shape=[jax.ShapeDtypeStruct((B, N, Cdim), dtype),
                    stat_shape, stat_shape],
         interpret=interpret,
+        name="field_encode",
     )(x)
     return q, scale[:, :, 0, 0], mins[:, :, 0, 0]
 
@@ -147,4 +148,5 @@ def field_decode(q: jax.Array, scale: jax.Array, mins: jax.Array,
         out_specs=row,
         out_shape=jax.ShapeDtypeStruct((B, N, Cdim), out_dtype),
         interpret=interpret,
+        name="field_decode",
     )(q, lanes(scale), lanes(mins))

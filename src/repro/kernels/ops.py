@@ -5,17 +5,26 @@ they lower through Mosaic.  Only the field codec is checked on the TPU:
 ``tests/test_tpu_compile.py`` compiles it for a described v5e chip and
 ``chip_smoke.py`` runs it on one.  ``ref.py`` holds the pure-jnp oracles
 used by the per-kernel allclose sweeps in tests/test_kernels.py.
+
+Importing this module mirrors the program's ``repro.obs`` spans into the
+JAX profiler (``jax.profiler.TraceAnnotation``, name only), so a profiler
+trace shows them on its host planes, on the clock of the device's
+operations.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro.obs.trace import set_span_mirror
+
 from . import ref
 from .field_codec import field_decode as _field_decode
 from .field_codec import field_encode as _field_encode
 from .flash_attention import flash_attention as _flash_attention
 from .rmsnorm import fused_rmsnorm as _fused_rmsnorm
+
+set_span_mirror(jax.profiler.TraceAnnotation)
 
 
 def _interpret() -> bool:

@@ -28,6 +28,17 @@ Design points:
   encode split the bench columns report.  All are pull-based; nothing
   runs unless asked.
 
+* **Per-span CPU time.**  An enabled span named in
+  :data:`CPU_TIMED_SPANS` also records the thread's CPU time between its
+  entry and exit (``cpu_ns``), so a slow span can be told apart as more
+  work or waiting.  Two ``thread_time_ns`` reads are a system call each,
+  so the other spans take none.
+* **A mirror onto another clock.**  :func:`set_span_mirror` installs a
+  factory of context managers that every span an enabled tracer opens
+  with ``with`` also enters — :mod:`repro.kernels.ops` installs
+  ``jax.profiler.TraceAnnotation``, so the spans land in the profiler's
+  trace beside the device's operations.
+
 This module is stdlib-only and imports nothing from ``repro`` except its
 sibling :mod:`.metrics`, so any layer (backends, executor, kernels) can
 import it without cycles.
@@ -40,7 +51,7 @@ import threading
 import time
 from collections import deque
 from contextvars import ContextVar
-from typing import Any, Dict, Iterable, List, Optional
+from typing import Any, Callable, ContextManager, Dict, List, Optional
 
 from .metrics import MetricsRegistry
 
@@ -61,6 +72,28 @@ PHASE_SPANS: Dict[str, frozenset] = {
 
 DEFAULT_CAPACITY = 1 << 16
 
+#: spans that record the thread's CPU time (``Span.cpu_ns``) when enabled:
+#: the write path's encode and per-object archive
+CPU_TIMED_SPANS = frozenset({"codec.encode", "io.archive"})
+
+#: factory of a context manager entered around every span an enabled
+#: tracer opens with ``with`` (see :func:`set_span_mirror`)
+_MIRROR: Optional[Callable[[str], ContextManager]] = None
+
+
+def set_span_mirror(
+        factory: Optional[Callable[[str], ContextManager]]) -> None:
+    """Mirror spans onto another timeline: while ``factory`` is installed,
+    each span an *enabled* tracer opens with ``with`` also enters
+    ``factory(name)`` and exits it when the span closes (``None``, the
+    default, mirrors nothing).  A disabled tracer never touches it.
+
+    Spans recorded after the fact (:meth:`Tracer.record_complete`, such as
+    ``executor.queue``) are not mirrored: their interval is over before
+    they are known."""
+    global _MIRROR
+    _MIRROR = factory
+
 
 class Span:
     """One finished (or in-flight) timed phase.
@@ -68,10 +101,13 @@ class Span:
     ``span_id``/``parent_id`` are tracer-local integers; ``parent_id`` is
     None for roots.  ``attrs`` is mutable while the span is open — callers
     set e.g. ``nbytes`` once known (``sp.attrs["nbytes"] = n``).
+    ``cpu_ns``, the thread's CPU time over the span, is set when a span
+    named in :data:`CPU_TIMED_SPANS` closes, and stays None on every
+    other span.
     """
 
     __slots__ = ("tracer", "name", "span_id", "parent_id", "thread_id",
-                 "t0_ns", "t1_ns", "attrs")
+                 "t0_ns", "t1_ns", "attrs", "cpu_ns")
 
     def __init__(self, tracer: "Tracer", name: str, span_id: int,
                  parent_id: Optional[int], thread_id: int, t0_ns: int,
@@ -84,18 +120,12 @@ class Span:
         self.t0_ns = t0_ns
         self.t1_ns: Optional[int] = None
         self.attrs = attrs
+        self.cpu_ns: Optional[int] = None
 
     @property
     def duration_us(self) -> float:
         end = self.t1_ns if self.t1_ns is not None else time.perf_counter_ns()
         return (end - self.t0_ns) / 1_000.0
-
-    def to_dict(self) -> Dict[str, Any]:
-        return {"name": self.name, "span_id": self.span_id,
-                "parent_id": self.parent_id, "thread_id": self.thread_id,
-                "t0_ns": self.t0_ns, "t1_ns": self.t1_ns,
-                "duration_us": round(self.duration_us, 3),
-                "attrs": dict(self.attrs)}
 
     def __repr__(self) -> str:  # pragma: no cover - debugging aid
         return (f"Span({self.name!r}, id={self.span_id}, "
@@ -124,7 +154,8 @@ _NOOP = _NoopSpan()
 class _SpanCM:
     """Context manager that opens a real span on a specific tracer."""
 
-    __slots__ = ("_tracer", "_name", "_attrs", "_span", "_token")
+    __slots__ = ("_tracer", "_name", "_attrs", "_span", "_token",
+                 "_mirror", "_cpu0")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
         self._tracer = tracer
@@ -141,13 +172,23 @@ class _SpanCM:
         span = Span(tr, self._name, next(tr._ids), parent_id,
                     threading.get_ident(), time.perf_counter_ns(),
                     self._attrs)
+        factory = _MIRROR
+        self._mirror = factory(self._name) if factory is not None else None
+        if self._mirror is not None:
+            self._mirror.__enter__()
         self._span = span
         self._token = _SPAN_VAR.set(span)
+        self._cpu0 = (time.thread_time_ns()
+                      if self._name in CPU_TIMED_SPANS else None)
         return span
 
     def __exit__(self, exc_type, exc, tb):
         span = self._span
         span.t1_ns = time.perf_counter_ns()
+        if self._cpu0 is not None:
+            span.cpu_ns = time.thread_time_ns() - self._cpu0
+        if self._mirror is not None:
+            self._mirror.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             span.attrs["error"] = exc_type.__name__
         _SPAN_VAR.reset(self._token)
@@ -281,14 +322,18 @@ class Tracer:
 
         Timestamps are perf-counter microseconds — consistent within a
         process, which is all Perfetto needs to lay out the timeline.
+        ``args`` holds the attrs and, where measured, ``cpu_ns``.
         """
         events = []
         for s in self.spans(since):
+            args = {k: _jsonable(v) for k, v in s.attrs.items()}
+            if s.cpu_ns is not None:
+                args["cpu_ns"] = s.cpu_ns
             events.append({
                 "name": s.name, "ph": "X", "pid": pid, "tid": s.thread_id,
                 "ts": s.t0_ns / 1_000.0,
                 "dur": round(s.duration_us, 3),
-                "args": {k: _jsonable(v) for k, v in s.attrs.items()},
+                "args": args,
             })
         return events
 
@@ -351,12 +396,6 @@ def current_span() -> Optional[Span]:
     return _SPAN_VAR.get()
 
 
-def current_tracer() -> Optional[Tracer]:
-    """The tracer owning the active span, or None outside any span."""
-    s = _SPAN_VAR.get()
-    return s.tracer if s is not None else None
-
-
 def span(name: str, **attrs: Any):
     """Ambient span: attach to whatever traced operation is in flight.
 
@@ -377,4 +416,4 @@ GLOBAL_TRACER = Tracer(enabled=False)
 
 
 __all__ = ["Span", "Tracer", "TraceBuffer", "GLOBAL_TRACER", "PHASE_SPANS",
-           "DEFAULT_CAPACITY", "span", "current_span", "current_tracer"]
+           "CPU_TIMED_SPANS", "DEFAULT_CAPACITY", "span", "current_span", "set_span_mirror"]

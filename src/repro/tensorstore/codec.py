@@ -35,6 +35,8 @@ from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
+from repro.obs.trace import span as obs_span
+
 _LANES = 128
 _RAW, _QUANT = 0, 1
 _BLOCK_CANDIDATES = (256, 128, 64, 32, 16, 8)
@@ -132,29 +134,41 @@ class FieldQuantCodec(Codec):
         dimension: one Pallas launch per distinct chunk shape (interior
         chunks of a write plan all share one), instead of one per chunk.
         Ineligible chunks take the raw fallback; output is byte-identical
-        to calling :meth:`encode` per chunk."""
+        to calling :meth:`encode` per chunk.
+
+        Each group runs in the stages ``codec.encode.stack`` (host copies
+        into one float32 batch), ``.launch`` (the batch's copy to the
+        device and the kernel's launch, which returns before the kernel
+        ends), ``.d2h`` (the wait for the kernel and the copy of its
+        results back) and ``.pack`` (containers): the same calls, and the
+        one wait, whether tracing is on or off."""
         out: List[bytes] = [b""] * len(arrs)
         by_shape: Dict[Tuple[int, ...], List[int]] = {}
-        contig = [np.ascontiguousarray(a) for a in arrs]
-        for i, a in enumerate(contig):
+        for i, a in enumerate(arrs):
             if self._eligible(a):
                 by_shape.setdefault(a.shape, []).append(i)
             else:
-                out[i] = bytes([_RAW]) + a.tobytes()
+                out[i] = bytes([_RAW]) + np.ascontiguousarray(a).tobytes()
         if by_shape:
             from repro.kernels import ops
-        for shape, idxs in by_shape.items():
-            flats = [contig[i].reshape(-1).astype(np.float32) for i in idxs]
-            n, rows, block = self._layout(flats[0].size)
-            stacked = np.stack([f[:n].reshape(rows, _LANES) for f in flats])
-            q, scale, mins = ops.field_encode(stacked, block=block,
-                                              bits=self.bits)
-            q, scale, mins = (np.asarray(q, self._qdtype),
-                              np.asarray(scale, np.float32),
-                              np.asarray(mins, np.float32))
-            for k, i in enumerate(idxs):
-                out[i] = self._container(rows, block, q[k], scale[k],
-                                         mins[k], flats[k][n:])
+        for idxs in by_shape.values():
+            with obs_span("codec.encode.stack", chunks=len(idxs)):
+                flats = [np.ascontiguousarray(arrs[i]).reshape(-1)
+                         .astype(np.float32) for i in idxs]
+                n, rows, block = self._layout(flats[0].size)
+                stacked = np.stack([f[:n].reshape(rows, _LANES)
+                                    for f in flats])
+            with obs_span("codec.encode.launch"):
+                q, scale, mins = ops.field_encode(stacked, block=block,
+                                                  bits=self.bits)
+            with obs_span("codec.encode.d2h"):
+                q, scale, mins = (np.asarray(q, self._qdtype),
+                                  np.asarray(scale, np.float32),
+                                  np.asarray(mins, np.float32))
+            with obs_span("codec.encode.pack"):
+                for k, i in enumerate(idxs):
+                    out[i] = self._container(rows, block, q[k], scale[k],
+                                             mins[k], flats[k][n:])
         return out
 
     def _decode_head(self, q: np.ndarray, scale: np.ndarray,
@@ -214,7 +228,9 @@ class FieldQuantCodec(Codec):
                      shapes: Sequence[Tuple[int, ...]],
                      dtype: np.dtype) -> List[np.ndarray]:
         """Batched inverse: equal-geometry quantised payloads (all interior
-        chunks of one array) decode through one kernel launch."""
+        chunks of one array) decode through one kernel launch, in the
+        stages ``codec.decode.stack`` (parse, stack), ``.launch``, ``.d2h``
+        and ``.unpack`` (tail, dtype, shape), as in :meth:`encode_batch`."""
         out: List[np.ndarray] = [None] * len(datas)  # type: ignore[list-item]
         groups: Dict[Tuple, List[int]] = {}
         for i, (d, s) in enumerate(zip(datas, shapes)):
@@ -225,15 +241,19 @@ class FieldQuantCodec(Codec):
                 rows, block = struct.unpack_from("<II", d, 1)
                 groups.setdefault((tuple(s), rows, block), []).append(i)
         for (shape, rows, block), idxs in groups.items():
-            parsed = [self._parse(datas[i]) for i in idxs]
-            heads = np.asarray(self._decode_head(
-                np.stack([p[2] for p in parsed]),
-                np.stack([p[3] for p in parsed]),
-                np.stack([p[4] for p in parsed]), block))
-            for k, i in enumerate(idxs):
-                out[i] = np.concatenate(
-                    [heads[k].reshape(-1), parsed[k][5]]).astype(
-                        dtype, copy=False).reshape(shape)
+            with obs_span("codec.decode.stack", chunks=len(idxs)):
+                parsed = [self._parse(datas[i]) for i in idxs]
+                q, scale, mins = (np.stack([p[j] for p in parsed])
+                                  for j in (2, 3, 4))
+            with obs_span("codec.decode.launch"):
+                heads = self._decode_head(q, scale, mins, block)
+            with obs_span("codec.decode.d2h"):
+                heads = np.asarray(heads)
+            with obs_span("codec.decode.unpack"):
+                for k, i in enumerate(idxs):
+                    out[i] = np.concatenate(
+                        [heads[k].reshape(-1), parsed[k][5]]).astype(
+                            dtype, copy=False).reshape(shape)
         return out
 
 

@@ -66,6 +66,7 @@ paper's object-store/POSIX trade-off, plus their composition:
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
@@ -771,6 +772,8 @@ class WritePlan:
             if sp is not None:
                 sp.attrs["nbytes"] = nbytes
         metrics.counter("codec.bytes_encoded").inc(nbytes)
+        metrics.counter("codec.values_encoded").inc(
+            sum(int(t.size) for t in tiles))
         idents = [arr.chunk_ident(self.tasks[pos][0]) for pos in stage]
         #: linear chunk ids per stage position — io.archive spans carry
         #: them so the checker can test lease coverage per archived chunk
@@ -978,7 +981,7 @@ class ReadPlan:
         coalesced read + one batched decode per I/O batch, through the
         bounded executor — the write path's RMW fetch."""
         arr = self.array
-        grid, codec = arr.grid, arr._codec
+        grid = arr.grid
         out: List[Optional[np.ndarray]] = [None] * len(self.tasks)
         for pos in self.missing:
             out[pos] = np.zeros(grid.chunk_shape(self.tasks[pos][0]),
@@ -987,13 +990,7 @@ class ReadPlan:
             out[pos] = cached.copy()    # cached entries are read-only
 
         def run_batch(positions: List[int], mh: MultiHandle) -> None:
-            shapes = [grid.chunk_shape(self.tasks[pos][0])
-                      for pos in positions]
-            parts = self._fetch(mh, len(positions))
-            with self.tracer.span("codec.decode", chunks=len(positions),
-                                  codec=codec.name):
-                chunks = codec.decode_batch(parts, shapes, arr.dtype)
-            for pos, chunk in zip(positions, chunks):
+            for pos, chunk in zip(positions, self._decode(positions, mh)):
                 self._populate_cache(pos, chunk)
                 out[pos] = chunk if chunk.flags.writeable else chunk.copy()
 
@@ -1019,6 +1016,22 @@ class ReadPlan:
         self.tracer.metrics.counter("codec.bytes_decoded").inc(nbytes)
         return parts
 
+    def _decode(self, positions: List[int],
+                mh: MultiHandle) -> List[np.ndarray]:
+        """Fetch one I/O batch and decode its chunks in one batched call
+        (equal-shape chunks share a kernel launch), counted into
+        ``codec.values_decoded``."""
+        arr = self.array
+        shapes = [arr.grid.chunk_shape(self.tasks[pos][0])
+                  for pos in positions]
+        parts = self._fetch(mh, len(positions))
+        with self.tracer.span("codec.decode", chunks=len(positions),
+                              codec=arr._codec.name):
+            chunks = arr._codec.decode_batch(parts, shapes, arr.dtype)
+        self.tracer.metrics.counter("codec.values_decoded").inc(
+            sum(math.prod(s) for s in shapes))
+        return chunks
+
     def execute(self, deadline: Optional[float] = None) -> np.ndarray:
         """Assemble the selection.  ``deadline`` (seconds) bounds the
         plan's facade-level retries via the ambient
@@ -1027,34 +1040,30 @@ class ReadPlan:
             raise TypeError("whole-chunk plan (for_chunks) has no selection "
                             "to assemble; use read_chunks()")
         arr = self.array
-        grid, codec = arr.grid, arr._codec
         with self.tracer.span("plan.execute", kind="read",
                               chunks=self.n_chunks,
                               batches=len(self.batches)), \
                 deadline_scope(deadline):
-            out = np.empty(grid.selection_shape(self.sel), arr.dtype)
+            out = np.empty(arr.grid.selection_shape(self.sel), arr.dtype)
             for pos in self.missing:
                 out[self.tasks[pos][2]] = 0
-            for pos, cached in self._cached.items():
-                _idx, chunk_sel, out_sel = self.tasks[pos]
-                out[out_sel] = cached[chunk_sel]
+            with self.tracer.span("plan.assemble",
+                                  chunks=len(self._cached)):
+                for pos, cached in self._cached.items():
+                    _idx, chunk_sel, out_sel = self.tasks[pos]
+                    out[out_sel] = cached[chunk_sel]
 
             def run_batch(positions: List[int], mh: MultiHandle) -> None:
-                # one coalesced read per batch, one batched decode
-                # (equal-shape chunks share a kernel launch); per-chunk
-                # payloads scatter into disjoint output regions →
-                # concurrent assembly is safe
-                shapes = [grid.chunk_shape(self.tasks[pos][0])
-                          for pos in positions]
-                parts = self._fetch(mh, len(positions))
-                with self.tracer.span("codec.decode",
-                                      chunks=len(positions),
-                                      codec=codec.name):
-                    chunks = codec.decode_batch(parts, shapes, arr.dtype)
-                for pos, chunk in zip(positions, chunks):
-                    self._populate_cache(pos, chunk)
-                    _idx, chunk_sel, out_sel = self.tasks[pos]
-                    out[out_sel] = chunk[chunk_sel]
+                # one coalesced read per batch; per-chunk payloads scatter
+                # into disjoint output regions → concurrent assembly is
+                # safe
+                chunks = self._decode(positions, mh)
+                with self.tracer.span("plan.assemble",
+                                      chunks=len(positions)):
+                    for pos, chunk in zip(positions, chunks):
+                        self._populate_cache(pos, chunk)
+                        _idx, chunk_sel, out_sel = self.tasks[pos]
+                        out[out_sel] = chunk[chunk_sel]
 
             arr.store.executor.map_ordered(
                 lambda b: run_batch(*b), self.batches,

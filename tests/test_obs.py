@@ -14,8 +14,15 @@ import pytest
 from repro.core import FDB, FDBConfig, LeaseConflictError, Meter
 from repro.obs import (Counter, Gauge, Histogram, MetricsRegistry, Tracer,
                        TraceBuffer)
-from repro.obs.trace import _NOOP, PHASE_SPANS, current_span, span
+from repro.obs.trace import (_NOOP, CPU_TIMED_SPANS, PHASE_SPANS,
+                             current_span, set_span_mirror, span)
 from repro.tensorstore import TensorStore
+from repro.tensorstore.codec import get_codec
+
+ENCODE_STAGES = {f"codec.encode.{s}"
+                 for s in ("stack", "launch", "d2h", "pack")}
+DECODE_STAGES = {f"codec.decode.{s}"
+                 for s in ("stack", "launch", "d2h", "unpack")}
 
 BACKENDS = ["daos", "rados", "posix", "s3"]
 
@@ -222,6 +229,100 @@ def test_metrics_thread_safety_smoke():
 
 
 # ---------------------------------------------------------------------------
+# span mirror, per-span CPU time
+# ---------------------------------------------------------------------------
+
+class _LoggedMirror:
+    def __init__(self, log, name):
+        self.log, self.name = log, name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+        return False
+
+
+@pytest.fixture
+def mirror_log(monkeypatch):
+    """A logging mirror for one test; the mirror installed before it (the
+    profiler's, once ``repro.kernels.ops`` is imported) comes back after."""
+    import repro.obs.trace as trace_mod
+    monkeypatch.setattr(trace_mod, "_MIRROR", None)
+    log = []
+    set_span_mirror(lambda name: _LoggedMirror(log, name))
+    return log
+
+
+def test_span_mirror_entered_and_exited_in_span_order(mirror_log):
+    tr = Tracer(enabled=True)
+    with tr.span("outer") as outer:
+        with span("inner"):
+            pass
+        with pytest.raises(ValueError):
+            with tr.span("failing"):
+                raise ValueError("x")
+        # measured after the fact: nothing to mirror
+        tr.record_complete("executor.queue", 0, 10, parent=outer)
+    assert mirror_log == [("enter", "outer"), ("enter", "inner"),
+                          ("exit", "inner"), ("enter", "failing"),
+                          ("exit", "failing"), ("exit", "outer")]
+
+
+def test_disabled_tracer_never_touches_the_mirror(mirror_log):
+    tr = Tracer(enabled=False)
+    with tr.span("off") as sp:
+        with span("ambient"):
+            assert sp is None
+    assert mirror_log == []
+
+
+def test_cpu_timed_spans_count_cpu_time():
+    assert CPU_TIMED_SPANS == {"codec.encode", "io.archive"}
+    tr = Tracer(enabled=True)
+    with tr.span("codec.encode", k=1) as sp:
+        with tr.span("codec.encode.stack") as inner:
+            assert int(np.ones(1 << 22, np.uint8).sum()) == 1 << 22
+    assert 0 < sp.cpu_ns
+    assert inner.cpu_ns is None         # not a CPU-timed name
+    q = tr.record_complete("io.archive", 0, 10)
+    assert q.cpu_ns is None             # measured after the fact
+    off = Tracer(enabled=False)
+    with off.span("io.archive") as none:
+        assert none is None
+    # the slot stays out of attrs, and reaches the exported args
+    assert sp.attrs == {"k": 1}
+    events = {e["name"]: e["args"] for e in tr.chrome_events()}
+    assert events == {"codec.encode": {"k": 1, "cpu_ns": sp.cpu_ns},
+                      "codec.encode.stack": {}, "io.archive": {}}
+
+
+def test_mirrored_spans_reach_the_profiler_trace(tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    import repro.obs.trace as trace_mod
+    from repro.kernels import ops  # noqa: F401  (installs the mirror)
+    assert trace_mod._MIRROR is jax.profiler.TraceAnnotation
+    tr = Tracer(enabled=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("plan.execute"):
+            with span("codec.decode.launch"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    assert {"plan.execute", "codec.decode.launch"} <= names
+
+
+# ---------------------------------------------------------------------------
 # instrumentation: context propagation through the I/O stack
 # ---------------------------------------------------------------------------
 
@@ -321,6 +422,47 @@ def test_lease_conflict_and_session_metrics(tmp_path):
     names = {s.name for s in tracer.spans()}
     assert {"lease.acquire", "session.close"} <= names
     fdb.close()
+
+
+def test_codec_stages_nest_under_codec_spans(tmp_path):
+    tracer = Tracer(enabled=True)
+    fdb, ts = make_store("daos", tmp_path, tracer=tracer)
+    x = np.random.default_rng(3).normal(size=(64, 512)).astype(np.float32)
+    ts.save(x, chunks=(32, 512), codec="field8")
+    np.testing.assert_allclose(ts.open()[8:40, :], x[8:40, :], atol=0.05)
+    spans = tracer.spans()
+    by_id = span_index(spans)
+    names = {s.name for s in spans}
+    assert ENCODE_STAGES | DECODE_STAGES | {"plan.assemble"} <= names
+    for s in spans:
+        parent = by_id.get(s.parent_id)
+        if s.name in ENCODE_STAGES:
+            assert parent.name == "codec.encode"
+        elif s.name in DECODE_STAGES:
+            assert parent.name == "codec.decode"
+        elif s.name == "plan.assemble":
+            assert parent.name == "plan.execute"
+    fdb.close()
+
+
+def test_codec_stages_change_no_bytes():
+    """Encode and decode give the same bytes with tracing on and off."""
+    codec = get_codec("field16")
+    rng = np.random.default_rng(5)
+    arrs = ([rng.normal(size=(4, 384)).astype(np.float32) for _ in range(3)]
+            + [rng.normal(size=(3, 300)), np.ones(7, np.float32)])
+    shapes = [a.shape for a in arrs]
+    off = codec.encode_batch(arrs)
+    decoded_off = codec.decode_batch(off, shapes, np.float32)
+    tr = Tracer(enabled=True)
+    with tr.span("codec.encode"):
+        on = codec.encode_batch(arrs)
+    assert on == off
+    with tr.span("codec.decode"):
+        decoded_on = codec.decode_batch(on, shapes, np.float32)
+    for a, b in zip(decoded_off, decoded_on):
+        assert a.tobytes() == b.tobytes()
+    assert ENCODE_STAGES | DECODE_STAGES <= {s.name for s in tr.spans()}
 
 
 # ---------------------------------------------------------------------------
