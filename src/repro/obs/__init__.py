@@ -9,12 +9,12 @@ from .locks import NamedLock, held_locks, set_lock_observer
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       DEFAULT_LATENCY_BUCKETS_US)
 from .trace import (GLOBAL_TRACER, PHASE_SPANS, Span, TraceBuffer, Tracer,
-                    current_span, set_span_mirror, span)
+                    count, current_span, set_span_mirror, span)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "DEFAULT_LATENCY_BUCKETS_US",
     "GLOBAL_TRACER", "PHASE_SPANS", "Span", "TraceBuffer", "Tracer",
-    "current_span", "set_span_mirror", "span",
+    "count", "current_span", "set_span_mirror", "span",
     "NamedLock", "held_locks", "set_lock_observer",
 ]

@@ -409,6 +409,16 @@ def span(name: str, **attrs: Any):
     return _SpanCM(s.tracer, name, attrs)
 
 
+def count(name: str, n: int = 1) -> None:
+    """Ambient counter, the twin of :func:`span`: add ``n`` to the counter
+    ``name`` of whatever traced operation is in flight (a layer with no
+    tracer handle, such as the codec, counts into its caller's tracer);
+    outside one, a no-op."""
+    s = _SPAN_VAR.get()
+    if s is not None and s.tracer.enabled:
+        s.tracer.metrics.counter(name).inc(n)
+
+
 #: process-wide default tracer, disabled out of the box — mirrors
 #: ``GLOBAL_METER``.  ``benchmarks.run --trace`` enables it; FDB clients
 #: use it unless constructed with a private tracer.
@@ -416,4 +426,5 @@ GLOBAL_TRACER = Tracer(enabled=False)
 
 
 __all__ = ["Span", "Tracer", "TraceBuffer", "GLOBAL_TRACER", "PHASE_SPANS",
-           "CPU_TIMED_SPANS", "DEFAULT_CAPACITY", "span", "current_span", "set_span_mirror"]
+           "CPU_TIMED_SPANS", "DEFAULT_CAPACITY", "span", "count", "current_span",
+           "set_span_mirror"]

@@ -16,7 +16,12 @@ vectorisation: equal-shape chunks are stacked onto the kernels' leading
 batch dimension and encoded (decoded) in ONE Pallas launch — grid over
 chunks × blocks — while ragged edge chunks fall back to the per-chunk path.
 Batched output is byte-identical to per-chunk encodes (blocks never
-straddle chunks), so the two paths interoperate freely.
+straddle chunks), so the two paths interoperate freely.  A decode group
+launches in slices of at most :func:`launch_cap` chunks (32, fewer where
+32 would not fit :data:`MAX_LAUNCH_BYTES`), each at the next power of two
+of its size (zero rows padded, dropped after), so a geometry uses at most
+log2(32) + 1 launch shapes, which the codec runs once, on zeros, before the
+geometry's first launch.
 
 Container layout (little-endian):
   [0]   marker: 0 = raw ndarray bytes, 1 = quantised
@@ -31,15 +36,37 @@ still decode, through the jnp reference decoder on the same device.
 from __future__ import annotations
 
 import struct
+import threading
 from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
+from repro.obs.trace import count as obs_count
 from repro.obs.trace import span as obs_span
 
 _LANES = 128
 _RAW, _QUANT = 0, 1
 _BLOCK_CANDIDATES = (256, 128, 64, 32, 16, 8)
+#: most chunks one decode launch takes: the default executor window
+#: (8 workers x 4), so a read stage of one geometry is one launch
+MAX_DECODE_BATCH = 32
+#: most float32 bytes one decode launch restores, so that eight workers'
+#: launches hold at most 512 MiB of device and host memory at once
+MAX_LAUNCH_BYTES = 64 << 20
+
+
+def launch_batch(n: int) -> int:
+    """Leading batch dimension a launch of ``n`` chunks runs at: the next
+    power of two (one chunk stays ``B = 1``)."""
+    return 1 << (n - 1).bit_length()
+
+
+def launch_cap(rows: int) -> int:
+    """Most chunks of ``rows`` quantised rows one decode launch takes: the
+    largest power of two within :data:`MAX_DECODE_BATCH` and
+    :data:`MAX_LAUNCH_BYTES` (at least one)."""
+    fit = min(MAX_DECODE_BATCH, MAX_LAUNCH_BYTES // (rows * _LANES * 4))
+    return 1 << (max(1, fit).bit_length() - 1)
 
 
 class Codec:
@@ -85,6 +112,9 @@ class FieldQuantCodec(Codec):
         self.bits = bits
         self.name = f"field{bits}"
         self._qdtype = np.int8 if bits == 8 else np.int16
+        #: (rows, block) of the geometries whose launch shapes have run
+        self._warm: set = set()
+        self._warm_lock = threading.Lock()
 
     def _eligible(self, arr: np.ndarray) -> bool:
         return (arr.dtype in (np.float32, np.float16, np.float64)
@@ -224,13 +254,35 @@ class FieldQuantCodec(Codec):
         return np.concatenate([head.reshape(-1), tail]).astype(
             dtype, copy=False).reshape(shape)
 
+    def _warm_launches(self, rows: int, block: int) -> int:
+        """Run every launch shape of this geometry (each power of two up to
+        :func:`launch_cap`) on zeros at its first decode, so that no later
+        decode compiles; returns the cap.  Warm geometries take no lock."""
+        cap = launch_cap(rows)
+        if (rows, block) in self._warm:
+            return cap
+        with self._warm_lock:
+            batch = 1
+            while (rows, block) not in self._warm and batch <= cap:
+                stats = np.zeros((batch, rows // block), np.float32)
+                self._decode_head(
+                    np.zeros((batch, rows, _LANES), self._qdtype), stats,
+                    stats, block).block_until_ready()
+                batch *= 2
+            self._warm.add((rows, block))
+        return cap
+
     def decode_batch(self, datas: Sequence[bytes],
                      shapes: Sequence[Tuple[int, ...]],
                      dtype: np.dtype) -> List[np.ndarray]:
         """Batched inverse: equal-geometry quantised payloads (all interior
-        chunks of one array) decode through one kernel launch, in the
-        stages ``codec.decode.stack`` (parse, stack), ``.launch``, ``.d2h``
-        and ``.unpack`` (tail, dtype, shape), as in :meth:`encode_batch`."""
+        chunks of one array) decode through one kernel launch per
+        :func:`launch_cap` of them, each at :func:`launch_batch` of its
+        size (the rows past the chunks are zeros, decoded and dropped), in
+        the stages ``codec.decode.stack`` (parse, stack),
+        ``.launch``, ``.d2h`` and ``.unpack`` (tail, dtype, shape), as in
+        :meth:`encode_batch`.  Each launch is counted in the ambient
+        counter ``codec.decode_launches``."""
         out: List[np.ndarray] = [None] * len(datas)  # type: ignore[list-item]
         groups: Dict[Tuple, List[int]] = {}
         for i, (d, s) in enumerate(zip(datas, shapes)):
@@ -240,21 +292,36 @@ class FieldQuantCodec(Codec):
             else:
                 rows, block = struct.unpack_from("<II", d, 1)
                 groups.setdefault((tuple(s), rows, block), []).append(i)
-        for (shape, rows, block), idxs in groups.items():
-            with obs_span("codec.decode.stack", chunks=len(idxs)):
-                parsed = [self._parse(datas[i]) for i in idxs]
-                q, scale, mins = (np.stack([p[j] for p in parsed])
-                                  for j in (2, 3, 4))
-            with obs_span("codec.decode.launch"):
-                heads = self._decode_head(q, scale, mins, block)
-            with obs_span("codec.decode.d2h"):
-                heads = np.asarray(heads)
-            with obs_span("codec.decode.unpack"):
-                for k, i in enumerate(idxs):
-                    out[i] = np.concatenate(
-                        [heads[k].reshape(-1), parsed[k][5]]).astype(
-                            dtype, copy=False).reshape(shape)
+        for (shape, rows, block), group in groups.items():
+            cap = self._warm_launches(rows, block)
+            for lo in range(0, len(group), cap):
+                idxs = group[lo:lo + cap]
+                obs_count("codec.decode_launches")
+                with obs_span("codec.decode.stack", chunks=len(idxs)):
+                    parsed = [self._parse(datas[i]) for i in idxs]
+                    q, scale, mins = (_stack_padded([p[j] for p in parsed])
+                                      for j in (2, 3, 4))
+                with obs_span("codec.decode.launch"):
+                    heads = self._decode_head(q, scale, mins, block)
+                with obs_span("codec.decode.d2h"):
+                    heads = np.asarray(heads)
+                with obs_span("codec.decode.unpack"):
+                    for k, i in enumerate(idxs):
+                        out[i] = np.concatenate(
+                            [heads[k].reshape(-1), parsed[k][5]]).astype(
+                                dtype, copy=False).reshape(shape)
         return out
+
+
+def _stack_padded(parts: List[np.ndarray]) -> np.ndarray:
+    """``np.stack(parts)`` with zero rows appended up to
+    :func:`launch_batch` of their number."""
+    batch = launch_batch(len(parts))
+    if batch == len(parts):
+        return np.stack(parts)
+    out = np.zeros((batch,) + parts[0].shape, parts[0].dtype)
+    np.stack(parts, out=out[:len(parts)])
+    return out
 
 
 CODECS: Dict[str, Codec] = {

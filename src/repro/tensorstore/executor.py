@@ -28,7 +28,8 @@ from __future__ import annotations
 import contextvars
 import threading
 import time
-from concurrent.futures import Future, ThreadPoolExecutor
+from concurrent.futures import FIRST_COMPLETED, Future, ThreadPoolExecutor
+from concurrent.futures import wait as wait_futures
 from typing import Any, Callable, Iterable, List, Optional
 
 from repro.obs import trace as _obs
@@ -62,6 +63,8 @@ class ChunkExecutor:
         self._lock = threading.Lock()
         self._in_flight = 0
         self.peak_in_flight = 0
+        #: ``fair`` map_ordered calls running now
+        self._fair_calls = 0
 
     # -- core API -------------------------------------------------------------
     def submit(self, fn: Callable[..., Any], *args: Any, **kw: Any) -> Future:
@@ -110,14 +113,14 @@ class ChunkExecutor:
 
     def map_ordered(self, fn: Callable[[Any], Any],
                     items: Iterable[Any],
-                    describe: Optional[Callable[[Any], str]] = None
-                    ) -> List[Any]:
+                    describe: Optional[Callable[[Any], str]] = None,
+                    fair: bool = False) -> List[Any]:
         """Run ``fn`` over ``items`` concurrently; results in input order.
 
         Items may be wildly mixed-size units of work — the tensorstore write
         path mixes direct chunk encodes with read-modify-write fetches, the
-        read path mixes single-chunk fetches with one-I/O multi-chunk group
-        reads — the bounded window simply admits whatever comes next.
+        read path maps stages that each fetch and decode up to a window of
+        chunks — the bounded window simply admits whatever comes next.
 
         The first raised exception propagates (after all futures settle, so
         no task outlives the call with shared state in hand) — annotated
@@ -125,9 +128,39 @@ class ChunkExecutor:
         a describer is given, and how many sibling tasks also failed), so a
         retried-then-exhausted chunk op surfaces with its context instead
         of a bare backend error.
+
+        ``fair`` calls keep at most their share of the workers busy:
+        ``max_workers`` divided by the fair calls running at that moment
+        (at least one).  Concurrent fair callers — readers whose items are
+        long stages of decode work — then progress at equal rates, however
+        many items each has, while a lone one uses every worker.
         """
         items = list(items)
-        futures = [self.submit(fn, item) for item in items]
+        if not fair:
+            return self._settle([self.submit(fn, item) for item in items],
+                                items, describe)
+        with self._lock:
+            self._fair_calls += 1
+        futures: List[Future] = []
+        try:
+            for item in items:
+                while True:
+                    running = [f for f in futures if not f.done()]
+                    if len(running) < max(1, self.max_workers
+                                          // self._fair_calls):
+                        break
+                    wait_futures(running, return_when=FIRST_COMPLETED)
+                futures.append(self.submit(fn, item))
+            return self._settle(futures, items, describe)
+        finally:
+            with self._lock:
+                self._fair_calls -= 1
+
+    @staticmethod
+    def _settle(futures: List[Future], items: List[Any],
+                describe: Optional[Callable[[Any], str]]) -> List[Any]:
+        """Wait for every future; results in order, or the first error
+        annotated with its item (see :meth:`map_ordered`)."""
         results: List[Any] = []
         first_error, first_pos, n_failed = None, -1, 0
         for pos, fut in enumerate(futures):

@@ -26,7 +26,13 @@ paper's object-store/POSIX trade-off, plus their composition:
   same storage unit — posix chunks of one data file — are grouped so adjacent
   ranges coalesce into single large reads (``FileRangeHandle`` merging),
   while object-store chunks keep one op in flight each.  ``read_ops()`` on
-  the plan reports the I/O-op count a read will issue.
+  the plan reports the I/O-op count a read will issue.  Execution is
+  *staged*, symmetric with writes: the I/O batches are split into stages
+  of at most one executor window of chunks (``ReadPlan.window``), and
+  each stage is one executor task that fetches its batches, decodes every
+  fetched chunk in one ``Codec.decode_batch`` call (one kernel launch per
+  chunk geometry) and assembles them; a plan's stages overlap on its
+  fair share of the executor's workers.
 * **Writes** (``write``, ``arr[sel] = values``, ``write_at``) build a
   :class:`WritePlan` — the mirror of the read side.  Every chunk the
   selection touches is resolved to its destination storage unit
@@ -67,7 +73,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import List, Mapping, Optional, Sequence, Tuple
+from typing import Callable, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -840,14 +846,28 @@ class ReadPlan:
     (posix chunks living in one writer's data file) merge, so adjacent
     chunks coalesce into single ranged reads — the POSIX backend's key read
     optimisation — while object-store chunks stay one independent op each,
-    which is what those backends want kept in flight.  Executing scatters
-    decoded chunks into the output array, one executor task per group.
+    which is what those backends want kept in flight.
 
-    Two consumption modes share the resolved batches: :meth:`execute`
-    assembles the selection into one output array (strided selections
-    scatter through their strided within-chunk slices), while
-    :meth:`read_chunks` — on plans built by :meth:`for_chunks` — returns
-    whole decoded chunks, the write path's coalesced RMW fetch.
+    Execution is staged, the mirror of :class:`WritePlan`: the I/O batches
+    are split, in plan order, into :attr:`stages` of at most one executor
+    window (:attr:`window` chunks, from the executor's ``max_in_flight``);
+    a single batch larger than the window is a stage of its own.  Each
+    stage is one executor task: it fetches its batches in order (an
+    object-store chunk stays one independent op), decodes every fetched
+    chunk in ONE :meth:`~.codec.Codec.decode_batch` call — one kernel
+    launch per chunk geometry, counted in ``codec.decode_launches`` — and
+    assembles them.  Stages run concurrently on the executor's workers,
+    each plan on its fair share of them (all of them when it runs alone),
+    so up to ``max_workers`` ops are in flight, as with one task per
+    batch, with one hand-off between threads per stage, not per chunk.
+    Only chunks the cache missed are staged; cached and never-written
+    chunks take no I/O.
+
+    Two consumption modes share the stages: :meth:`execute` assembles the
+    selection into one output array (strided selections scatter through
+    their strided within-chunk slices), while :meth:`read_chunks` — on
+    plans built by :meth:`for_chunks` — returns whole decoded chunks, the
+    write path's coalesced RMW fetch.
     """
 
     def __init__(self, array: "ChunkedArray", sel, squeeze,
@@ -966,6 +986,19 @@ class ReadPlan:
             ([present[i] for i in group],
              MultiHandle([handles[i] for i in group]))
             for group in group_mergeable(handles)]
+        #: most chunks fetched and decoded together (the executor's
+        #: in-flight bound, resolved at plan time as WritePlan.window is)
+        self.window = max(1, store.executor.max_in_flight)
+        #: indices into ``batches`` per stage: consecutive batches holding
+        #: at most ``window`` chunks, or one batch larger than that
+        self.stages: List[List[int]] = []
+        held = 0
+        for b, (positions, _mh) in enumerate(self.batches):
+            if not self.stages or held + len(positions) > self.window:
+                self.stages.append([])
+                held = 0
+            self.stages[-1].append(b)
+            held += len(positions)
 
     @property
     def n_chunks(self) -> int:
@@ -977,9 +1010,9 @@ class ReadPlan:
 
     def read_chunks(self) -> List[np.ndarray]:
         """Decode every planned chunk *whole*, in task order — always
-        writable, missing chunks as zeros (fill-value convention).  One
-        coalesced read + one batched decode per I/O batch, through the
-        bounded executor — the write path's RMW fetch."""
+        writable, missing chunks as zeros (fill-value convention).  Staged
+        like :meth:`execute`: coalesced reads through the bounded executor,
+        one batched decode per stage — the write path's RMW fetch."""
         arr = self.array
         grid = arr.grid
         out: List[Optional[np.ndarray]] = [None] * len(self.tasks)
@@ -989,23 +1022,18 @@ class ReadPlan:
         for pos, cached in self._cached.items():
             out[pos] = cached.copy()    # cached entries are read-only
 
-        def run_batch(positions: List[int], mh: MultiHandle) -> None:
-            for pos, chunk in zip(positions, self._decode(positions, mh)):
+        def take(positions: List[int], chunks: List[np.ndarray]) -> None:
+            for pos, chunk in zip(positions, chunks):
                 self._populate_cache(pos, chunk)
                 out[pos] = chunk if chunk.flags.writeable else chunk.copy()
-
-        arr.store.executor.map_ordered(
-            lambda b: run_batch(*b), self.batches,
-            describe=lambda b: (
-                f"op=io.fetch backend={arr.store.fdb.config.backend} "
-                f"chunks={[self.tasks[pos][0] for pos in b[0]]}"))
+        self._run_stages(take)
         return out              # type: ignore[return-value]
 
     def _fetch(self, mh: MultiHandle, n_chunks: int) -> List[bytes]:
         """One coalesced backend read, wrapped in the ``io.fetch`` span
         (the ``t_io`` phase) and counted into ``codec.bytes_decoded`` —
-        shared by both consumption modes, and running on an executor worker
-        thread with the caller's span context propagated."""
+        running on an executor worker thread with the caller's span
+        context propagated."""
         backend = self.array.store.fdb.config.backend
         with self.tracer.span("io.fetch", ops=mh.read_ops(),
                               chunks=n_chunks, backend=backend) as sp:
@@ -1016,15 +1044,40 @@ class ReadPlan:
         self.tracer.metrics.counter("codec.bytes_decoded").inc(nbytes)
         return parts
 
+    def _run_stages(self, take: Callable[[List[int], List[np.ndarray]],
+                                         None]) -> None:
+        """One executor task per stage: fetch its I/O batches in plan
+        order, decode all their chunks in one call, and hand
+        (positions-into-tasks, decoded chunks) to ``take``.  The plan
+        keeps at most its fair share of the executor's workers busy
+        (``map_ordered(fair=True)``): a lone plan overlaps its stages on
+        every worker, concurrent plans progress at equal rates."""
+        backend = self.array.store.fdb.config.backend
+
+        def chunks_of(stage: List[int]) -> list:
+            return [self.tasks[pos][0] for b in stage
+                    for pos in self.batches[b][0]]
+
+        def run_stage(stage: List[int]) -> None:
+            batches = [self.batches[b] for b in stage]
+            positions = [pos for group, _mh in batches for pos in group]
+            parts = [part for group, mh in batches
+                     for part in self._fetch(mh, len(group))]
+            take(positions, self._decode(positions, parts))
+
+        self.array.store.executor.map_ordered(
+            run_stage, self.stages, fair=True,
+            describe=lambda stage: (
+                f"op=io.fetch backend={backend} chunks={chunks_of(stage)}"))
+
     def _decode(self, positions: List[int],
-                mh: MultiHandle) -> List[np.ndarray]:
-        """Fetch one I/O batch and decode its chunks in one batched call
-        (equal-shape chunks share a kernel launch), counted into
+                parts: List[bytes]) -> List[np.ndarray]:
+        """Decode one stage's chunks in one batched call (equal-shape
+        chunks share a kernel launch), counted into
         ``codec.values_decoded``."""
         arr = self.array
         shapes = [arr.grid.chunk_shape(self.tasks[pos][0])
                   for pos in positions]
-        parts = self._fetch(mh, len(positions))
         with self.tracer.span("codec.decode", chunks=len(positions),
                               codec=arr._codec.name):
             chunks = arr._codec.decode_batch(parts, shapes, arr.dtype)
@@ -1042,7 +1095,8 @@ class ReadPlan:
         arr = self.array
         with self.tracer.span("plan.execute", kind="read",
                               chunks=self.n_chunks,
-                              batches=len(self.batches)), \
+                              batches=len(self.batches),
+                              stages=len(self.stages)), \
                 deadline_scope(deadline):
             out = np.empty(arr.grid.selection_shape(self.sel), arr.dtype)
             for pos in self.missing:
@@ -1053,23 +1107,16 @@ class ReadPlan:
                     _idx, chunk_sel, out_sel = self.tasks[pos]
                     out[out_sel] = cached[chunk_sel]
 
-            def run_batch(positions: List[int], mh: MultiHandle) -> None:
-                # one coalesced read per batch; per-chunk payloads scatter
-                # into disjoint output regions → concurrent assembly is
-                # safe
-                chunks = self._decode(positions, mh)
+            def take(positions: List[int], chunks: List[np.ndarray]) -> None:
+                # stages scatter into disjoint output regions → concurrent
+                # assembly is safe
                 with self.tracer.span("plan.assemble",
                                       chunks=len(positions)):
                     for pos, chunk in zip(positions, chunks):
                         self._populate_cache(pos, chunk)
                         _idx, chunk_sel, out_sel = self.tasks[pos]
                         out[out_sel] = chunk[chunk_sel]
-
-            arr.store.executor.map_ordered(
-                lambda b: run_batch(*b), self.batches,
-                describe=lambda b: (
-                    f"op=io.fetch backend={arr.store.fdb.config.backend} "
-                    f"chunks={[self.tasks[pos][0] for pos in b[0]]}"))
+            self._run_stages(take)
         if self.flips:          # negative-step axes: one client-side flip
             out = out[tuple(slice(None, None, -1) if a in self.flips
                             else slice(None) for a in range(out.ndim))]

@@ -14,7 +14,7 @@ import pytest
 from repro.core import FDB, FDBConfig, LeaseConflictError, Meter
 from repro.obs import (Counter, Gauge, Histogram, MetricsRegistry, Tracer,
                        TraceBuffer)
-from repro.obs.trace import (_NOOP, CPU_TIMED_SPANS, PHASE_SPANS,
+from repro.obs.trace import (_NOOP, CPU_TIMED_SPANS, PHASE_SPANS, count,
                              current_span, set_span_mirror, span)
 from repro.tensorstore import TensorStore
 from repro.tensorstore.codec import get_codec
@@ -95,6 +95,19 @@ def test_ambient_span_joins_active_tracer():
         with span("ambient", nbytes=3) as b:
             assert b.tracer is tr and b.parent_id == a.span_id
     assert [s.name for s in tr.spans()] == ["ambient", "outer"]
+
+
+def test_ambient_count_joins_active_tracer():
+    tr = Tracer(enabled=True)
+    count("ambient.n", 5)                   # no traced operation: no-op
+    with tr.span("outer"):
+        count("ambient.n", 2)
+        count("ambient.n")
+    assert tr.metrics.snapshot()["ambient.n"]["value"] == 3
+    off = Tracer(enabled=False)
+    with off.span("outer"):
+        count("ambient.n")
+    assert "ambient.n" not in off.metrics.snapshot()
 
 
 def test_foreign_tracer_parent_treated_as_root():

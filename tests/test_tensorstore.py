@@ -6,6 +6,8 @@ chunks (asserted via engine ``Meter`` op counts), chunk-boundary edge cases,
 and codec on/off parity — plus the executor's bounded in-flight window and
 the batched ``FDB.archive_many`` semantics.
 """
+import itertools
+import struct
 import time
 
 import numpy as np
@@ -1284,6 +1286,374 @@ def test_write_plan_staged_by_executor_window(tmp_path):
     assert arr.read_plan((slice(None),)).read_ops() == 1
     ex.shutdown()
     fdb.close()
+
+
+# ---------------------------------------------------------------------------
+# staged reads: a stage of missed chunks decodes in one batched call
+# ---------------------------------------------------------------------------
+
+def _staged_store(backend, tmp_path, window=32, cache_bytes=0):
+    """(fdb, store, executor) with a known executor window, traced so the
+    codec's ambient counters count."""
+    from repro.obs import Tracer
+    fdb = FDB(FDBConfig(backend=backend, schema="tensor",
+                        root=str(tmp_path / "fdb"),
+                        chunk_cache_bytes=cache_bytes),
+              tracer=Tracer(enabled=True))
+    ex = ChunkExecutor(max_workers=4, max_in_flight=window)
+    ts = TensorStore(fdb, {"store": "s", "array": "a", "writer": "w0"},
+                     executor=ex)
+    return fdb, ts, ex
+
+
+def _per_chunk(arr):
+    """The whole array as per-chunk ``Codec.decode`` gives it (chunks
+    never written as zeros)."""
+    out = np.zeros(arr.shape, arr.dtype)
+    for idx in itertools.product(*(range(n) for n in arr.n_chunks)):
+        data = arr.store.fdb.retrieve(arr.chunk_ident(idx)).read()
+        if data:
+            out[arr.grid.chunk_slices(idx)] = arr._codec.decode(
+                data, arr.grid.chunk_shape(idx), arr.dtype)
+    return out
+
+
+def _warm(arr):
+    """Run every launch shape of the array's chunk geometries once, so the
+    launches a test sees next are the read's own."""
+    first = {}
+    for idx in itertools.product(*(range(n) for n in arr.n_chunks)):
+        data = arr.store.fdb.retrieve(arr.chunk_ident(idx)).read()
+        if data:
+            first.setdefault(arr.grid.chunk_shape(idx), data)
+    for shape, data in first.items():
+        arr._codec.decode_batch([data] * 3, [shape] * 3, arr.dtype)
+
+
+@pytest.fixture
+def launches(monkeypatch):
+    """Leading batch dimension of every field_decode launch, in order."""
+    from repro.kernels import ops
+    seen = []
+    real = ops.field_decode
+
+    def field_decode(q, *args, **kw):
+        seen.append(q.shape[0])
+        return real(q, *args, **kw)
+    monkeypatch.setattr(ops, "field_decode", field_decode)
+    return seen
+
+
+def _launch_count(fdb):
+    return fdb.metrics().get("codec.decode_launches", {}).get("value", 0)
+
+
+@pytest.mark.parametrize("backend", ["daos", "posix"])
+@pytest.mark.parametrize("key", [
+    (slice(None), slice(None)),                    # the whole array
+    (slice(3, 61), slice(100, 900)),               # contiguous window
+    (slice(None, None, 3), slice(7, None, 97)),    # strided
+    (slice(None, None, -2), slice(900, 50, -33)),  # negative steps
+    (5, slice(None)),                              # scalar index
+    (-1, 1000),                                    # scalars only
+])
+def test_staged_read_matches_per_chunk_decode(backend, key, tmp_path):
+    """70 rows of two (1, 400) chunks and a ragged (1, 300) edge chunk:
+    every selection reads back byte-identical to per-chunk decodes."""
+    fdb, ts, ex = _staged_store(backend, tmp_path)
+    x = np.random.default_rng(60).normal(size=(70, 1100)).astype(np.float32)
+    arr = ts.save(x, chunks=(1, 400), codec="field16")
+    want = _per_chunk(arr)[key]
+    got = arr.read_plan(key).execute()
+    assert got.shape == np.shape(want) and got.dtype == want.dtype
+    assert got.tobytes() == np.ascontiguousarray(want).tobytes()
+    ex.shutdown()
+    fdb.close()
+
+
+@pytest.mark.parametrize("backend,stages", [
+    ("daos", [32, 32, 6]),          # one op per chunk, staged
+    ("posix", [70]),                # one coalesced read, one stage
+])
+def test_staged_read_launches(backend, stages, tmp_path, launches):
+    """70 same-shape misses under a window of 32: launches of at most 32
+    chunks, each at a power-of-two batch, the last padded; the counter
+    counts exactly those launches."""
+    fdb, ts, ex = _staged_store(backend, tmp_path)
+    x = np.random.default_rng(61).normal(size=(70, 384)).astype(np.float32)
+    arr = ts.save(x, chunks=(1, 384), codec="field8")
+    want = _per_chunk(arr)
+    _warm(arr)
+    launches.clear()
+    plan = arr.read_plan((slice(None), slice(None)))
+    assert plan.window == 32
+    assert [sum(len(plan.batches[b][0]) for b in st)
+            for st in plan.stages] == stages
+    before = _launch_count(fdb)
+    got = plan.execute()
+    assert sorted(launches) == [8, 32, 32]
+    assert _launch_count(fdb) - before == 3
+    assert got.tobytes() == want.tobytes()
+    ex.shutdown()
+    fdb.close()
+
+
+@pytest.mark.parametrize("backend", ["daos", "posix"])
+@pytest.mark.parametrize("key,batch", [
+    ((0, slice(0, 400)), [1]),              # one chunk: B = 1, as before
+    ((0, slice(None)), [2, 1]),             # the ragged edge: its own group
+])
+def test_small_plans_launch_per_geometry(backend, key, batch, tmp_path,
+                                         launches):
+    fdb, ts, ex = _staged_store(backend, tmp_path)
+    x = np.random.default_rng(62).normal(size=(2, 1100)).astype(np.float32)
+    arr = ts.save(x, chunks=(1, 400), codec="field16")
+    want = _per_chunk(arr)[key]
+    _warm(arr)
+    launches.clear()
+    before = _launch_count(fdb)
+    got = arr[key]
+    assert launches == batch
+    assert _launch_count(fdb) - before == len(batch)
+    assert got.tobytes() == want.tobytes()
+    ex.shutdown()
+    fdb.close()
+
+
+@pytest.mark.parametrize("backend", ["daos", "posix"])
+def test_staged_read_with_cached_and_missing_chunks(backend, tmp_path,
+                                                    launches):
+    """Cached and never-written chunks take no stage; the misses between
+    them are staged and decoded in their batches."""
+    fdb, ts, ex = _staged_store(backend, tmp_path, window=8,
+                                cache_bytes=1 << 20)
+    x = np.random.default_rng(63).normal(size=(40, 384)).astype(np.float32)
+    arr = ts.create(x.shape, x.dtype, chunks=(1, 384), codec="field16")
+    arr.write_at((slice(0, 30), slice(None)), x[:30])   # rows 30.. missing
+    arr[4:12:3, :]                  # rows 4, 7 and 10 now cached
+    want = _per_chunk(arr)
+    _warm(arr)
+    launches.clear()
+    before = _launch_count(fdb)
+    plan = arr.read_plan((slice(None), slice(None)))
+    assert plan.cache_hits == 3 and len(plan.missing) == 10
+    staged = sum(len(plan.batches[b][0]) for st in plan.stages for b in st)
+    assert staged == 27
+    assert plan.execute().tobytes() == want.tobytes()
+    # daos: stages of 8, 8, 8 and 3 misses, in any order across the
+    # executor's workers; posix: one coalesced batch
+    assert sorted(launches) == {"daos": [4, 8, 8, 8], "posix": [32]}[backend]
+    assert _launch_count(fdb) - before == len(launches)
+    ex.shutdown()
+    fdb.close()
+
+
+@pytest.mark.parametrize("backend", ["daos", "posix"])
+def test_rmw_read_chunks_staged(backend, tmp_path, launches):
+    """The write path's whole-chunk fetch takes the same stages, and its
+    chunks are byte-identical to per-chunk decodes and writable."""
+    from repro.tensorstore import ReadPlan
+    fdb, ts, ex = _staged_store(backend, tmp_path, window=4)
+    x = np.random.default_rng(64).normal(size=(10, 384)).astype(np.float32)
+    arr = ts.save(x, chunks=(1, 384), codec="field8")
+    want = _per_chunk(arr)
+    _warm(arr)
+    launches.clear()
+    before = _launch_count(fdb)
+    plan = ReadPlan.for_chunks(arr, [(i, 0) for i in range(10)])
+    chunks = plan.read_chunks()
+    for i, c in enumerate(chunks):
+        assert c.flags.writeable
+        assert c.tobytes() == want[i:i + 1].tobytes()
+    assert sorted(launches) == {"daos": [2, 4, 4], "posix": [16]}[backend]
+    assert _launch_count(fdb) - before == len(launches)
+    # a strided write patches every row through those fetches
+    arr[:, ::2] = 0.0
+    want[:, ::2] = 0.0
+    got = arr.read()
+    assert np.abs(got - want).max() <= (x.max() - x.min()) / 255
+    ex.shutdown()
+    fdb.close()
+
+
+def test_failing_fetch_in_a_stage_is_annotated(tmp_path, monkeypatch):
+    """A fetch that fails inside a stage raises through the executor's
+    annotation, naming the failed stage and its chunks."""
+    from repro.tensorstore import ReadPlan
+    fdb, ts, ex = _staged_store("daos", tmp_path, window=4)
+    x = np.zeros((10, 384), np.float32)
+    arr = ts.save(x, chunks=(1, 384), codec="field16")
+    real = ReadPlan._fetch
+
+    def fetch(self, mh, n_chunks):
+        if self.tasks[self.batches[5][0][0]][0] == (5, 0) and \
+                mh is self.batches[5][1]:
+            raise OSError("object 5 unreachable")
+        return real(self, mh, n_chunks)
+    monkeypatch.setattr(ReadPlan, "_fetch", fetch)
+    with pytest.raises(OSError, match="unreachable") as err:
+        arr.read()
+    notes = " ".join(getattr(err.value, "__notes__", [])) + str(err.value)
+    assert "first failure of 1/3" in notes
+    assert ("op=io.fetch backend=daos chunks=[(4, 0), (5, 0), (6, 0), "
+            "(7, 0)]") in notes
+    ex.shutdown()
+    fdb.close()
+
+
+def test_concurrent_staged_reads(tmp_path):
+    """Readers on more threads than cores, with a short switch interval,
+    stage, warm and decode one new geometry concurrently: every answer
+    is byte-identical to per-chunk decodes."""
+    import sys
+    import threading
+    fdb, ts, ex = _staged_store("daos", tmp_path, window=8)
+    x = np.random.default_rng(66).normal(size=(24, 1408)).astype(np.float32)
+    arr = ts.save(x, chunks=(1, 704), codec="field8")     # 5-row chunks
+    want = _per_chunk(arr)
+    keys = [(slice(i, None, 3), slice(None)) for i in range(3)] + \
+        [(slice(None), slice(i * 100, None, 7)) for i in range(9)]
+    errors, done = [], []
+
+    def reader(key):
+        try:
+            for _ in range(3):
+                got = arr.read_plan(key).execute()
+                assert got.tobytes() == np.ascontiguousarray(
+                    want[key]).tobytes()
+            done.append(key)
+        except BaseException as e:   # noqa: BLE001 - reported below
+            errors.append(e)
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=reader, args=(k,)) for k in keys]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+    assert errors == [] and len(done) == len(keys)
+    ex.shutdown()
+    fdb.close()
+
+
+@pytest.fixture
+def compiles():
+    """Every XLA compilation while the test runs."""
+    import jax
+    seen = []
+
+    def on_event(event, _duration, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            seen.append(event)
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    yield seen
+    jax.monitoring.unregister_event_duration_listener(on_event)
+
+
+def _decode_counted(codec, datas, shapes):
+    """``decode_batch`` inside a traced span: (chunks, launches counted)."""
+    from repro.obs import Tracer
+    tracer = Tracer(enabled=True)
+    with tracer.span("codec.decode"):
+        got = codec.decode_batch(datas, shapes, np.float32)
+    return got, tracer.metrics.snapshot().get(
+        "codec.decode_launches", {}).get("value", 0)
+
+
+@pytest.mark.parametrize("n,batches", [
+    (1, [1]), (3, [4]), (12, [16]), (32, [32]), (33, [32, 1]),
+    (70, [32, 32, 8]),
+])
+def test_decode_batch_launch_shapes(n, batches, launches, compiles):
+    """A group of any size decodes in slices of at most 32 chunks, each at
+    a power-of-two batch, byte-identical to per-chunk decodes; once a
+    geometry met a group of several, no size compiles again."""
+    from repro.tensorstore.codec import MAX_DECODE_BATCH, launch_batch
+    assert MAX_DECODE_BATCH == 32
+    assert [launch_batch(k) for k in (1, 2, 3, 5, 8, 9, 32)] == \
+        [1, 2, 4, 8, 8, 16, 32]
+    codec = get_codec("field8")
+    rng = np.random.default_rng(65)
+    arrs = [rng.normal(size=(5, 128)).astype(np.float32) for _ in range(n)]
+    datas = codec.encode_batch(arrs)
+    shapes = [a.shape for a in arrs]
+    want = [codec.decode(d, s, np.float32).tobytes()
+            for d, s in zip(datas, shapes)]
+    codec.decode_batch([datas[0]] * 3, [shapes[0]] * 3, np.float32)
+    launches.clear()
+    compiles.clear()
+    got, counted = _decode_counted(codec, datas, shapes)
+    assert [g.tobytes() for g in got] == want
+    assert launches == batches and counted == len(batches)
+    assert compiles == []
+
+
+def test_first_decode_warms_the_geometry_ladder(launches, monkeypatch):
+    """A geometry's first decode runs every launch size up to its cap on
+    zeros, once; the cap holds a launch within ``MAX_LAUNCH_BYTES``, so a
+    whole ERA5 field decodes one chunk a launch."""
+    from repro.tensorstore import codec as codec_mod
+    assert [codec_mod.launch_cap(r) for r in (1304, 2048, 360, 105440)] \
+        == [32, 32, 32, 1]
+    codec = get_codec("field16")
+    arr = np.random.default_rng(67).normal(size=(3, 1920)).astype(
+        np.float32)                         # a geometry no other test uses
+    data = codec.encode(arr)
+    rows, block = struct.unpack_from("<II", data, 1)
+    assert (rows, block) not in codec._warm
+    monkeypatch.setattr(codec_mod, "MAX_LAUNCH_BYTES", 5 * rows * 128 * 4)
+    assert codec_mod.launch_cap(rows) == 4
+    codec.decode_batch([data], [arr.shape], np.float32)
+    assert launches == [1, 2, 4, 1]
+    launches.clear()
+    got = codec.decode_batch([data] * 6, [arr.shape] * 6, np.float32)
+    assert launches == [4, 2]
+    want = codec.decode(data, arr.shape, np.float32).tobytes()
+    assert all(g.tobytes() == want for g in got)
+
+
+def test_fair_map_ordered_takes_its_share_of_the_workers():
+    """A lone fair call runs on every worker; with another fair call
+    running, each keeps at most half of them busy."""
+    import threading
+    ex = ChunkExecutor(max_workers=4)
+    lock, busy, peak = threading.Lock(), [0], [0]
+
+    def work(i):
+        with lock:
+            busy[0] += 1
+            peak[0] = max(peak[0], busy[0])
+        time.sleep(0.02)
+        with lock:
+            busy[0] -= 1
+        return i
+    assert ex.map_ordered(work, range(12), fair=True) == list(range(12))
+    assert peak[0] == 4
+    started, release = threading.Event(), threading.Event()
+
+    def hold(_):
+        started.set()
+        release.wait(10)
+    other = threading.Thread(
+        target=lambda: ex.map_ordered(hold, [0], fair=True))
+    other.start()
+    started.wait(10)
+    peak[0] = 0
+    try:
+        assert ex.map_ordered(work, range(12), fair=True) == list(range(12))
+        assert peak[0] == 2
+        # an unfair call still fills the window
+        peak[0] = 0
+        ex.map_ordered(work, range(12))
+        assert peak[0] == 3             # every worker the held task leaves
+    finally:
+        release.set()
+        other.join()
+    ex.shutdown()
 
 
 # ---------------------------------------------------------------------------
