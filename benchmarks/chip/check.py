@@ -1,9 +1,10 @@
 """The comparison that decides ``correct``.
 
-Every kept answer of the window is held to the reference
-(:mod:`.reference`): ``err_<codec>`` is the worst, over the answers of the
-fields stored with that codec, of the answer's worst error over the
-reference's worst error on the same values.  Each declared precision is
+Every kept answer of the window is held to the cell's generator's plain
+reference (for field cells, :mod:`.reference`): ``err_<codec>`` is the
+worst, over the answers of the arrays stored with that codec
+(``refs.codec``), of the answer's worst error over the reference's worst
+error on the same values.  Each declared precision is
 compared on its own, so that a fault in the 8-bit field cannot hide
 behind the 16-bit one.  A run is correct when it kept at least one answer,
 no work it issued failed or went unanswered, and each ``err_<codec>`` is
@@ -18,21 +19,21 @@ from __future__ import annotations
 
 from typing import Dict, List
 
-from .load import Answer, References
+from .load import Answer
 from .reference import err_ratio
 
 #: the nearest precision below each declared one
 LOWER_BITS = {16: 8, 8: 4}
 
 
-def compare(answers: List[Answer], refs: References,
-            control: References = None) -> Dict[str, float]:
-    """Worst ``err_ratio`` over ``answers``, per codec; with ``control``,
-    the control's answers (the lower-precision reference) take the
-    program's place."""
+def compare(answers: List[Answer], refs, control=None) -> Dict[str, float]:
+    """Worst ``err_ratio`` over ``answers``, per codec; ``refs`` and
+    ``control`` are a generator's ``References``, and with ``control`` the
+    control's answers (the lower-precision reference) take the program's
+    place."""
     worst: Dict[str, float] = {}
     for a in answers:
-        codec = refs.store.fields[a.array[0]]["codec"]
+        codec = refs.codec(a.array)
         truth, ref = refs[a.array].select(a.sel)
         result = a.result if control is None else \
             control[a.array].select(a.sel)[1]

@@ -1,5 +1,8 @@
-"""The general traffic generator: the store of one configuration, and the
-clients that one traffic file lists.
+"""The field store's traffic generator: the store of one configuration,
+and the clients that one traffic file lists.  ``generators/fields.py``
+hands it to ``run.py``, for every traffic file that names no other
+generator; ``Work`` and ``Answer`` are what every generator reports (the
+names a generator module exposes: :mod:`.spec`).
 
 A configuration (``configs/<name>.json``) gives the fields, their chunks
 and codecs, the store's client settings and how steps are laid out:
@@ -431,6 +434,10 @@ class References:
         self.host = host
         self.bits_of = bits_of or (lambda bits: bits)
         self._arrays: Dict[tuple, RefArray] = {}
+
+    def codec(self, key: Tuple[str, Optional[int]]) -> str:
+        """The codec the field of stored array ``key`` declares."""
+        return self.store.fields[key[0]]["codec"]
 
     def __getitem__(self, key: Tuple[str, Optional[int]]) -> RefArray:
         arr = self._arrays.get(key)

@@ -4,16 +4,21 @@
         --seconds <s> --trace <0|1>
 
 The run refuses to start, printing no result, unless JAX's devices are
-TPUs and at least as many as the cell asks for.  Set-up makes the cell's
-fields on the chip from ``--seed``, archives what the window reads and
-runs every shape the window uses once (JAX's persistent compilation
-cache lives in ``<checkout>/.jax_cache`` unless ``JAX_COMPILATION_CACHE_DIR``
-says otherwise).  The window then drives the cell's traffic for
-``--seconds``; with ``--trace 1`` a profiler trace and the program's spans
-cover it and the per-layer metrics are reported, otherwise the end-to-end
-ones.  After the window every kept answer is held to the numpy reference
-(:mod:`check`).  The last line of standard output is one JSON object;
-the numbers compared, with their limits, close standard error.
+TPUs and at least as many as the cell asks for.  Everything the cell
+deploys comes from its generator module (``generators/<name>.py``, named
+by the traffic file, ``fields`` by default; what it exposes:
+:mod:`spec`), so this file names no deployment.  Set-up makes the cell's
+data on the chip from ``--seed`` (``make_data``), builds the store and
+its clients, which archive what the window reads and run every shape the
+window uses once (JAX's persistent compilation cache lives in
+``<checkout>/.jax_cache`` unless ``JAX_COMPILATION_CACHE_DIR`` says
+otherwise).  The window then drives the cell's traffic for ``--seconds``;
+with ``--trace 1`` a profiler trace and the program's spans cover it and
+the per-layer metrics are reported, otherwise the end-to-end ones, which
+count the window's ``Work`` by kind.  After the window every kept answer
+is held to the generator's plain reference (:mod:`check`).  The last
+line of standard output is one JSON object; the numbers compared, with
+their limits, close standard error.
 
 ``--control 1`` puts the lower-precision reference in the program's place
 for the comparison; it must come out as not correct.
@@ -41,7 +46,7 @@ import numpy as np  # noqa: E402
 from repro.core import reset_engines  # noqa: E402
 from repro.obs.trace import Tracer  # noqa: E402
 
-from benchmarks.chip import check, fields, load, trace_reduce  # noqa: E402
+from benchmarks.chip import check, trace_reduce  # noqa: E402
 from benchmarks.chip.context import Context  # noqa: E402
 from benchmarks.chip.peaks import codec_hbm_bytes, peaks  # noqa: E402
 from benchmarks.chip.spec import Cell, find_cell  # noqa: E402
@@ -149,7 +154,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool = False,
     dev = jax.devices()[0]
     t_init = time.perf_counter()
 
-    made = fields.make_fields(cell.config, seed)
+    gen = cell.generator
+    made = gen.make_data(cell.config, seed)
     host = {k: np.asarray(v) for k, v in made.items()}
     for v in made.values():
         v.delete()
@@ -158,8 +164,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool = False,
 
     tracer = Tracer(enabled=False, capacity=1 << 21)
     reset_engines()
-    store = load.Store(cell.config, tracer)
-    traffic = load.Traffic(cell.traffic, store, host, seed)
+    store = gen.Store(cell.config, tracer)
+    traffic = gen.Traffic(cell.traffic, store, host, seed)
     traffic.setup()
     t_ready = time.perf_counter()
     setup = {"setup_s": t_ready - T_START, "init_s": t_init - T_START,
@@ -208,8 +214,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool = False,
     store.close()
     reset_engines()
 
-    refs = load.References(store, host)
-    lower = load.References(store, host, check.LOWER_BITS.get) \
+    refs = gen.References(store, host)
+    lower = gen.References(store, host, check.LOWER_BITS.get) \
         if control else None
     err = check.compare(answers, refs, lower)
     work = traffic.work

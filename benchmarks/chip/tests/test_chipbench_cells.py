@@ -1,7 +1,8 @@
 """Every traffic kind of the benchmark, driven end to end on the CPU at a
 tiny size (interpret mode); the control and the faults that ``correct``
-must catch; the refusal to run without a TPU; and a cell, configuration
-and metric added as files only."""
+must catch; the refusal to run without a TPU; a cell, configuration and
+metric added as files only; and a generator added as files only, with the
+default generator the same as the one a traffic file names."""
 from __future__ import annotations
 
 import json
@@ -46,7 +47,7 @@ def _run(tree, cell, trace=False, control=False, seed=2 ** 31 + 5):
 
 @pytest.mark.parametrize("cell,metrics", [
     ("archive", {"setup_s", "write_gbps", "stored_ratio"}),
-    ("pgen", {"setup_s", "read_p95_ms", "read_gbps"}),
+    ("pgen", {"setup_s", "read_p95_ms"}),
     ("train", {"setup_s", "read_gbps", "stored_ratio"}),
     ("hot", {"setup_s", "read_p95_ms", "read_gbps"}),
 ])
@@ -66,10 +67,27 @@ def test_traced_run_reads_spans_and_counters(tree):
     r = _run(tree, "pgen", trace=True)
     assert r["correct"]
     assert {"decode_ms_per_req.pgen", "cache_hit_rate.pgen",
-            "writer_late_ms.pgen"} <= set(r["metrics"])
+            "writer_late_ms.pgen", "read_gbps.pgen"} <= set(r["metrics"])
+    assert r["metrics"]["read_gbps.pgen"]["value"] > 0
     # no device plane on the CPU: the device metrics say nothing
     assert "device_idle_share.pgen" not in r["metrics"]
     assert r["window"]["codec"]["decode_elements"] > 0
+
+
+def test_pgen_read_gbps_reader():
+    from benchmarks.chip.context import Context
+    from benchmarks.chip.load import Work
+    from benchmarks.chip.spec import load_reader
+    read = load_reader(
+        Path(run.__file__).parent / "metrics" / "read_gbps.pgen.py")
+    work = [Work("read", 0.0, 0.0, 1.0, 3 * 10 ** 9),
+            Work("read", 0.0, 0.0, 1.0, 10 ** 9, error="lost"),
+            Work("step", 0.0, 0.0, 1.0, 5 * 10 ** 9)]
+    ctx = Context(trace=None, spans=[], counters={}, codec={}, work=work,
+                  window_s=2.0, peaks={})
+    assert read(ctx) == 1.5
+    ctx.work = work[1:]
+    assert read(ctx) is None
 
 
 def test_same_seed_same_fields():
@@ -178,3 +196,264 @@ def test_cell_config_and_metric_added_as_files(tmp_path):
     assert set(r["metrics"]) == {"setup_s", "read_p95_ms"}
     with pytest.raises(KeyError):
         find_cell("no-such-cell", base)
+
+
+#: A generator that is not shipped: seeded arrays archived whole, as raw
+#: bytes, through ``repro.core.FDB`` under the checkpoint schema; one
+#: closed-loop writer archives every array of the next step and flushes,
+#: one closed-loop reader retrieves a random array of the newest step.
+BLOBS = '''
+import threading
+import time
+import traceback
+from types import SimpleNamespace
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from benchmarks.chip.fields import key_from_seed
+from benchmarks.chip.load import Answer, Work
+
+
+def make_data(config, seed):
+    shape = (config["distinct_steps"], config["arrays"], config["size"])
+    make = jax.jit(lambda key: jax.random.normal(key, shape, jnp.float32))
+    return {"w": make(key_from_seed(seed))}
+
+
+class Store:
+    def __init__(self, config, tracer=None):
+        from repro.core import FDB, FDBConfig
+        fdb = FDBConfig(backend="daos", schema="ckpt")
+        self.producer = FDB(fdb, tracer=tracer)
+        self.consumer = FDB(fdb, tracer=tracer)
+
+    @staticmethod
+    def ident(step, i):
+        return {"run": "r", "kind": "state", "step": step, "host": "h0",
+                "tensor": i, "shard": 0}
+
+    def close(self):
+        self.producer.close()
+        self.consumer.close()
+
+
+class Traffic:
+    def __init__(self, spec, store, host, seed):
+        self.spec, self.store, self.values = spec, store, host["w"]
+        self.rng = np.random.default_rng([int(seed), 1])
+        self.committed, self.work, self.answers = [], [], []
+        self.unfinished = self.unreadable = 0
+        self.first_error = None
+        self._lock = threading.Lock()
+
+    def write_step(self, k):
+        rows = self.values[k % len(self.values)]
+        for i, row in enumerate(rows):
+            self.store.producer.archive(self.store.ident(k, i), row.tobytes())
+        self.store.producer.flush()
+        with self._lock:
+            self.committed.append(k)
+        return rows.nbytes
+
+    def read(self, step, i):
+        handle = self.store.consumer.retrieve(self.store.ident(step, i))
+        return np.frombuffer(handle.read(), np.float32)
+
+    def _answer(self, step, i, out):
+        self.answers.append(Answer((step % len(self.values), i), (), out))
+
+    def setup(self):
+        self.write_step(0)
+        self.read(0, 0)
+
+    def _do(self, w, fn):
+        try:
+            out = fn()
+        except Exception as exc:
+            w.error, out = repr(exc), None
+            self.first_error = self.first_error or traceback.format_exc()
+        w.done = time.perf_counter()
+        with self._lock:
+            self.work.append(w)
+        return out
+
+    def _writer(self, deadline):
+        k = 1
+        while time.perf_counter() < deadline:
+            w = Work("step", time.perf_counter(), time.perf_counter())
+            w.nbytes = self._do(w, lambda: self.write_step(k)) or 0
+            k += 1
+
+    def _reader(self, deadline):
+        while time.perf_counter() < deadline:
+            with self._lock:
+                step = self.committed[-1]
+            i = int(self.rng.integers(self.values.shape[1]))
+            w = Work("read", time.perf_counter(), time.perf_counter())
+            out = self._do(w, lambda: self.read(step, i))
+            if out is not None:
+                w.nbytes = out.nbytes
+                if len(self.answers) < self.spec["keep"]:
+                    self._answer(step, i, out)
+
+    def run(self, seconds):
+        t0 = time.perf_counter()
+        threads = [threading.Thread(target=f, args=(t0 + seconds,))
+                   for f in (self._writer, self._reader)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join()
+        return t0, max(w.done for w in self.work)
+
+    def read_back(self):
+        step = self.committed[-1]
+        for i in range(self.values.shape[1]):
+            self._answer(step, i, self.read(step, i))
+
+    def collect(self):
+        return self.answers
+
+    def stored_ratio(self):
+        stored = sum(loc.length for _ident, loc in
+                     self.store.producer.list({"run": "r", "kind": "state"}))
+        return stored / (len(self.committed) * self.values[0].nbytes)
+
+
+class References:
+    def __init__(self, store, host, bits_of=None):
+        self.values = host["w"]
+
+    def codec(self, key):
+        return "raw"
+
+    def __getitem__(self, key):
+        values = self.values[key[0]][key[1]]
+        return SimpleNamespace(select=lambda sel: (values[sel], values[sel]))
+'''
+
+
+def _blobs_tree(base: Path) -> Path:
+    """The tiny tree with a ``blobs`` cell whose generator, configuration,
+    traffic and per-layer metric are files added to it."""
+    tiny.make_tree(base)
+    chip = base / "benchmarks" / "chip"
+    (chip / "generators" / "blobs.py").write_text(BLOBS)
+    (chip / "configs" / "tiny-blobs.json").write_text(json.dumps(
+        {"name": "tiny-blobs", "distinct_steps": 2, "arrays": 4,
+         "size": 4096, "check": {"err_ratio_limit": {"raw": 0}}}))
+    (chip / "traffic" / "blobs.json").write_text(json.dumps(
+        {"generator": "blobs", "keep": 4}))
+    (chip / "metrics" / "steps_per_s.blobs.py").write_text(
+        "def read(ctx):\n    return len(ctx.done('step')) / ctx.window_s\n")
+    bench = json.loads((base / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "tiny-blobs", "source": "test",
+                             "file": "benchmarks/chip/configs/tiny-blobs.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "blobs", "config": "tiny-blobs",
+                               "traffic": "blobs", "chips": 1, "why": "t"})
+    bench["per_layer"].append({"name": "steps_per_s.blobs", "unit": "1/s",
+                               "better": "higher", "source": "host_clock",
+                               "layer": "load generator",
+                               "moves": "write_gbps", "workloads": ["blobs"]})
+    for m in bench["end_to_end"]:
+        if m["name"] in ("write_gbps", "read_p95_ms", "read_gbps"):
+            m["workloads"].append("blobs")
+    (base / "BENCHMARK.json").write_text(json.dumps(bench))
+    return base
+
+
+def _no_field_store(monkeypatch):
+    from repro.data.pipeline import ChunkedFieldStore
+
+    def refuse(*_a, **_kw):
+        raise AssertionError("the blobs cell built a ChunkedFieldStore")
+    monkeypatch.setattr(ChunkedFieldStore, "__init__", refuse)
+
+
+def test_generator_added_as_files(tmp_path, monkeypatch):
+    base = _blobs_tree(tmp_path)
+    _no_field_store(monkeypatch)
+    cell = find_cell("blobs", base)
+    assert cell.generator.__file__.endswith("blobs.py")
+    e2e = {"setup_s", "write_gbps", "read_p95_ms", "read_gbps"}
+    assert {m.name for m in cell.end_to_end} == e2e
+    assert [m.name for m in cell.per_layer] == ["steps_per_s.blobs"]
+    r = run.run_cell(cell, seed=2 ** 31 + 33, seconds=0.4)
+    assert r["correct"], r["checks"]
+    assert set(r["metrics"]) == e2e
+    assert r["checks"]["err_raw"] == {"value": 0.0, "limit": 0}
+    assert r["checks"]["checked"]["value"] >= 4
+    assert r["window"]["steps"] > 0 and r["window"]["reads"] > 0
+    assert r["window"]["stored_ratio"] == 1.0
+    r = run.run_cell(cell, seed=2 ** 31 + 34, seconds=0.4, trace=True)
+    assert r["correct"], r["checks"]
+    assert set(r["metrics"]) == {"steps_per_s.blobs"}
+    assert r["metrics"]["steps_per_s.blobs"]["value"] > 0
+
+
+def test_generator_added_as_files_catches_an_altered_answer(
+        tmp_path, monkeypatch):
+    from repro.core.handle import MultiHandle
+    base = _blobs_tree(tmp_path)
+    real = MultiHandle.read
+
+    def read(self):
+        data = bytearray(real(self))
+        data[1] ^= 0x40                 # an answer altered
+        return bytes(data)
+    monkeypatch.setattr(MultiHandle, "read", read)
+    r = run.run_cell(find_cell("blobs", base), seed=2 ** 31 + 35,
+                     seconds=0.4)
+    assert not r["correct"]
+    assert r["checks"]["err_raw"]["value"] > 0
+
+
+def test_missing_generator_file_is_refused(tmp_path):
+    base = _blobs_tree(tmp_path)
+    chip = base / "benchmarks" / "chip"
+    (chip / "generators" / "blobs.py").unlink()
+    with pytest.raises(FileNotFoundError):
+        find_cell("blobs", base)
+    (chip / "generators" / "blobs.py").write_text("make_data = None\n")
+    with pytest.raises(AttributeError):
+        find_cell("blobs", base)
+
+
+@pytest.fixture(scope="module")
+def steady_trees(tmp_path_factory):
+    """Two tiny trees, alike but that one's traffic files name the
+    ``fields`` generator.  Every step holds the same fields, so the answers
+    kept do not hang on how many steps a window commits."""
+    trees = []
+    for named in (False, True):
+        base = tiny.make_tree(tmp_path_factory.mktemp("steady"))
+        chip = base / "benchmarks" / "chip"
+        (chip / "configs" / "tiny-step.json").write_text(
+            json.dumps(dict(tiny.STEP_CONFIG, distinct_steps=1)))
+        if named:
+            for name, traffic in tiny.TRAFFIC.items():
+                (chip / "traffic" / f"{name}.json").write_text(
+                    json.dumps(dict(traffic, generator="fields")))
+        trees.append(base)
+    return trees
+
+
+@pytest.mark.parametrize("cell", ["archive", "pgen", "train", "hot"])
+def test_named_fields_generator_runs_as_the_default(steady_trees, cell):
+    runs = []
+    for base in steady_trees:
+        c = find_cell(cell, base)
+        assert c.generator.__file__.endswith("fields.py")
+        runs.append(run.run_cell(c, seed=2 ** 31 + 41, seconds=0.6))
+    default, named = runs
+    assert default["correct"] and named["correct"]
+    assert set(default["metrics"]) == set(named["metrics"])
+    assert default["window"]["stored_ratio"] == \
+        named["window"]["stored_ratio"]
+    errs = {k: v for k, v in default["checks"].items()
+            if k.startswith("err_")}
+    assert errs and errs == {k: v for k, v in named["checks"].items()
+                             if k.startswith("err_")}

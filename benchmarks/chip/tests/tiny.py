@@ -64,11 +64,14 @@ CELLS = {"archive": "tiny-step", "pgen": "tiny-step", "train": "tiny-time",
 
 def make_tree(base: Path, end_to_end=None, per_layer=None) -> Path:
     """Write ``base/BENCHMARK.json`` and the benchmark's directory with the
-    tiny configurations and traffic, and the real metric readers."""
+    tiny configurations and traffic, and the real generators and metric
+    readers."""
     chip = base / "benchmarks" / "chip"
     for sub in ("configs", "traffic"):
         (chip / sub).mkdir(parents=True, exist_ok=True)
-    shutil.copytree(CHIP / "metrics", chip / "metrics", dirs_exist_ok=True)
+    for sub in ("generators", "metrics"):
+        shutil.copytree(CHIP / sub, chip / sub, dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     for cfg in (STEP_CONFIG, TIME_CONFIG):
         (chip / "configs" / f"{cfg['name']}.json").write_text(json.dumps(cfg))
     for name, traffic in TRAFFIC.items():
