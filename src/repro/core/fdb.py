@@ -1209,14 +1209,22 @@ class WriterSession:
             return [k + (e,) for k, e in sorted(self._held.items(),
                                                 key=lambda kv: kv[0][2:])]
 
-    def release_all(self) -> None:
-        """Release every ledgered lease (stale entries release as no-ops)."""
+    def release_all(self, identifier=None) -> int:
+        """Release every ledgered lease, or, given an ``identifier``, every
+        one under its (dataset, collocation) key (stale entries release as
+        no-ops); returns the number released.  Releasing does not flush:
+        callers flush a dirty session first (:meth:`close` does)."""
+        scope = None if identifier is None else \
+            self.fdb._lease_split(identifier)
         with self._lock:
-            held, self._held = list(self._held), {}
+            held = [k for k in self._held if scope is None or k[:2] == scope]
+            for k in held:
+                del self._held[k]
         for dataset, collocation, resource, lo, hi in held:
             self.fdb._release_lease_split(dataset, collocation, resource,
                                           lo, hi, self.writer_id,
                                           exact=True)
+        return len(held)
 
     def mark_dirty_chunks(self, identifier, resource: str,
                           chunk_ids: Sequence[int]) -> None:

@@ -91,28 +91,47 @@ class ChunkedFieldStore:
                            tree=self.tree)
 
     # -- producer side -----------------------------------------------------
+    def _create(self, name: str, shape, dtype, chunks: Optional[tuple],
+                codec: Optional[str]) -> ChunkedArray:
+        ts = self._ts(name)
+        kw = dict(chunks=chunks or self.chunks, codec=codec or self.codec)
+        try:
+            arr = ts.create(shape, dtype, **kw)
+        except LayoutMismatchError:
+            # layout changed: the array's dataset is exactly (store,
+            # array), so a wipe removes every stale chunk before
+            # re-creating
+            self.wipe_field(name)
+            arr = ts.create(shape, dtype, **kw)
+        self._opened[name] = arr
+        return arr
+
     def put_field(self, name: str, values: np.ndarray,
                   chunks: Optional[tuple] = None,
                   codec: Optional[str] = None) -> ChunkedArray:
-        ts = self._ts(name)
         values = np.asarray(values)
         with self.fdb.tracer.span("field.put", field=name,
                                   nbytes=values.nbytes):
-            try:
-                arr = ts.create(values.shape, values.dtype,
-                                chunks=chunks or self.chunks,
-                                codec=codec or self.codec)
-            except LayoutMismatchError:
-                # layout changed: the array's dataset is exactly (store,
-                # array), so a wipe removes every stale chunk before
-                # re-creating
-                self.wipe_field(name)
-                arr = ts.create(values.shape, values.dtype,
-                                chunks=chunks or self.chunks,
-                                codec=codec or self.codec)
+            arr = self._create(name, values.shape, values.dtype, chunks,
+                               codec)
             # commit() is the visibility barrier — don't flush per field
             arr.write(values, flush=False)
-        self._opened[name] = arr
+        return arr
+
+    def declare_field(self, name: str, shape, dtype,
+                      chunks: Optional[tuple] = None,
+                      codec: Optional[str] = None) -> ChunkedArray:
+        """Archive a field's metadata with no chunks, record it in the
+        consolidated tree and flush, so that other clients' writers can
+        open the field and fill it window by window
+        (:meth:`FieldWriter.write_window`) before any value exists.
+        Unwritten chunks read as missing (``fill_missing``).  A layout that
+        differs from a stored one wipes the field first, as
+        :meth:`put_field` does.  The flush publishes everything else this
+        client archived too."""
+        with self.fdb.tracer.span("field.declare", field=name):
+            arr = self._create(name, shape, dtype, chunks, codec)
+            self.commit()
         return arr
 
     def commit(self) -> None:
@@ -266,13 +285,21 @@ class ChunkedFieldStore:
         Several writers (e.g. parallel assimilation tasks, ensemble
         members) may update *one* field concurrently: each writer's window
         acquires the covering chunk-range leases at plan time, so disjoint
-        windows proceed in parallel — through one FDB client and one
-        bounded executor — while overlapping windows fail fast with
-        ``LeaseConflictError`` instead of racing to a silent last-flush
-        merge.  All writers share this store's collocation key (the
-        ``writer`` schema dim), so consumers read one coherent array; the
-        *session* identity exists for leases and per-session flush
-        barriers, not for placement.
+        windows proceed in parallel while overlapping windows fail fast
+        with ``LeaseConflictError`` instead of racing to a silent
+        last-flush merge.  All writers share this store's collocation key
+        (the ``writer`` schema dim), so consumers read one coherent array;
+        the *session* identity exists for leases and per-session dirty
+        tracking, not for placement.
+
+        Writers opened here share this store's FDB client and executor, and
+        so its flush: any one writer's :meth:`FieldWriter.commit` publishes
+        every writer's archives.  Writers that each need their own barrier
+        (I/O servers that publish their share of a step independently) take
+        one ``ChunkedFieldStore`` each, built with the same ``store``,
+        ``writer`` and ``FDBConfig``: separate clients of one deployment,
+        which share its lease table.  One of them declares the fields they
+        fill (:meth:`declare_field`).
 
         ``lease_block=True`` flips the overlap posture from fail-fast to
         wait: plan-time acquires queue (up to ``lease_timeout`` seconds)
@@ -282,7 +309,8 @@ class ChunkedFieldStore:
         (``docs/workflows.md``).
 
         Use as a context manager; :meth:`FieldWriter.commit` is the
-        visibility barrier, and closing flushes (if dirty) then releases
+        visibility barrier, :meth:`FieldWriter.release` gives up a
+        committed field, and closing flushes (if dirty) then releases
         every lease the writer still holds.
         """
         return FieldWriter(self, self.fdb.session(
@@ -296,26 +324,36 @@ class ChunkedFieldStore:
 
 class FieldWriter:
     """One producer task writing windows of shared fields under chunk-range
-    leases — returned by :meth:`ChunkedFieldStore.writer`."""
+    leases — returned by :meth:`ChunkedFieldStore.writer`.
+
+    Its barrier is its client's: :meth:`commit` publishes whatever any
+    session of the same FDB client archived.  A writer that needs a barrier
+    of its own is opened on a ``ChunkedFieldStore`` of its own (see
+    :meth:`ChunkedFieldStore.writer`).  A writer that moves on to new
+    fields, as an I/O server does from step to step, gives each field up
+    once it is committed (:meth:`release`), so that neither its leases nor
+    its cached opens grow with the fields it has written."""
 
     def __init__(self, store: ChunkedFieldStore, session: WriterSession):
         self._store = store
         self.session = session
-        #: session-bound opens are cached per field (metadata re-reads are
-        #: pure overhead; layout changes mid-session are not supported)
+        #: session-bound opens are cached per field until released
+        #: (metadata re-reads are pure overhead; layout changes mid-session
+        #: are not supported)
         self._opened: Dict[str, ChunkedArray] = {}
 
     @property
     def writer_id(self) -> str:
         return self.session.writer_id
 
+    def _base(self, name: str) -> Dict[str, str]:
+        return {"store": self._store.store, "array": name,
+                "writer": self._store.writer_key}
+
     def _open(self, name: str) -> ChunkedArray:
         arr = self._opened.get(name)
         if arr is None:
-            ts = TensorStore(None, {"store": self._store.store,
-                                    "array": name,
-                                    "writer": self._store.writer_key},
-                             session=self.session)
+            ts = TensorStore(None, self._base(name), session=self.session)
             arr = self._opened[name] = ts.open()
         return arr
 
@@ -323,10 +361,11 @@ class FieldWriter:
         """Chunk-aligned in-place update of a field window under this
         writer's leases: the covering chunk ranges are acquired at plan
         time (``LeaseConflictError`` if another writer holds any of them,
-        before any byte moves) and stay held until :meth:`close` — a
-        :meth:`commit` publishes the data but deliberately keeps the
-        windows owned, so a producer retains them across commits.  The new
-        chunk versions become visible at :meth:`commit`, exactly like
+        before any byte moves) and stay held until :meth:`release` or
+        :meth:`close` — a :meth:`commit` publishes the data but
+        deliberately keeps the windows owned, so a producer retains them
+        across commits.  The new chunk versions become visible at
+        :meth:`commit`, exactly like
         :meth:`ChunkedFieldStore.write_window`.  RMW fetches for partially
         covered chunks are lease-protected, and this session's earlier
         unflushed archives pre-flush per *session*, not per client."""
@@ -338,10 +377,28 @@ class FieldWriter:
         return arr
 
     def commit(self) -> None:
-        """The visibility barrier for everything this writer archived
-        (client-level flush: FDB rule 3).  Held leases stay held — a
-        writer keeps its windows across commits until it closes."""
+        """The visibility barrier for everything this writer archived.  It
+        is a client-level flush (FDB rule 3), so it also publishes what
+        other sessions of the same client archived.  Held leases stay held
+        — a writer keeps its windows across commits until it releases the
+        field or closes."""
         self.session.flush()
+
+    def release(self, name: str) -> int:
+        """Give up field ``name``: flush if this writer is dirty, release
+        every chunk-range lease it holds on the field, and drop its cached
+        open.  Returns the number of leases released.  The field stays
+        readable; writing it again re-opens it and re-acquires leases."""
+        tracer = self.session.fdb.tracer
+        with tracer.span("field.release", field=name,
+                         writer=self.writer_id) as sp:
+            if self.session.dirty:
+                self.session.flush()
+            n = self.session.release_all(self._base(name))
+            self._opened.pop(name, None)
+            if sp is not None:
+                sp.attrs["leases"] = n
+        return n
 
     def close(self) -> None:
         """Flush if dirty, then release every lease this writer holds."""

@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 from repro.core import (FDB, FDBConfig, LeaseConflictError, StaleLeaseError)
+from repro.obs.trace import GLOBAL_TRACER
 from repro.tensorstore import TensorStore
 
 BASE = {"store": "s", "array": "a", "writer": "w0"}
@@ -322,6 +323,60 @@ def test_field_store_concurrent_writers():
         wl.commit()
     assert (st.read_window("t2m", slice(16, 48), slice(None)) == 9.0).all()
     st.close()
+
+
+@pytest.mark.parametrize("codec", ["field16", "field8", "raw"])
+def test_declared_field_filled_by_writer_clients(tmp_path, codec):
+    """The I/O-server pattern: one client declares a field, three writer
+    clients of the same deployment each fill their own levels and commit
+    behind their own barrier.  The chunk objects match one ``put_field``
+    of the same values byte for byte; a level whose writer has not
+    committed reads as missing; a released writer holds nothing."""
+    from repro.data.pipeline import ChunkedFieldStore
+    x = (280 + 10 * np.random.default_rng(3).normal(size=(6, 1300))
+         ).astype(np.float32)
+    cfg = FDBConfig(backend="posix", root=str(tmp_path / "fdb"))
+    coord, *io = [ChunkedFieldStore("nwp", cfg, chunks=(1, 512),
+                                    codec=codec) for _ in range(4)]
+    coord.declare_field("t_s0", x.shape, x.dtype)
+    writers = [st.writer(f"io{i}") for i, st in enumerate(io)]
+    for lvl in range(6):
+        writers[lvl % 3].write_window("t_s0", x[lvl:lvl + 1],
+                                      slice(lvl, lvl + 1))
+    writers[0].commit()
+    writers[1].commit()
+    early = ChunkedFieldStore("nwp", cfg)
+    np.testing.assert_array_equal(
+        early.read_window("t_s0", slice(0, 2), fill_missing=False),
+        early.read_window("t_s0", slice(0, 2)))
+    with pytest.raises(KeyError):       # io2's levels: archived, unpublished
+        early.read_window("t_s0", slice(2, 3), fill_missing=False)
+    writers[2].commit()
+    # io0 still holds its committed levels
+    with pytest.raises(LeaseConflictError):
+        writers[1].write_window("t_s0", x[3:4], slice(3, 4))
+
+    coord.put_field("ref", x)
+    coord.commit()
+    arr, ref = coord.open_field("t_s0"), coord.open_field("ref")
+    for idx in arr.grid.all_indices():
+        assert coord.fdb.retrieve(arr.chunk_ident(idx)).read() == \
+            coord.fdb.retrieve(ref.chunk_ident(idx)).read(), idx
+    consumer = ChunkedFieldStore("nwp", cfg)
+    np.testing.assert_array_equal(
+        consumer.read_window("t_s0", fill_missing=False),
+        consumer.read_window("ref", fill_missing=False))
+
+    assert [w.release("t_s0") for w in writers] == [2, 2, 2]
+    for w in writers:
+        assert w.session.held_leases == [] and w._opened == {}
+    assert coord.fdb.lease_holders(
+        {"store": "nwp", "array": "t_s0", "writer": "prod0"}, "g0") == []
+    releases = [s for s in GLOBAL_TRACER.spans() if s.name == "field.release"]
+    assert [s.attrs["leases"] for s in releases] == [2, 2, 2]
+    assert any(s.name == "field.declare" for s in GLOBAL_TRACER.spans())
+    for st in (coord, *io, early, consumer):
+        st.close()
 
 
 @pytest.mark.parametrize("backend", ["daos", "posix"])

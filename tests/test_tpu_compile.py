@@ -28,10 +28,15 @@ from repro.tensorstore.codec import FieldQuantCodec
 
 #: (case, chunk shapes encoded together, bits).  O1280 levels hold
 #: 6,599,680 points: ``t`` is stored field16 in (1, 262144) chunks (edge
-#: chunk 46,080 points), ``z`` field8 under auto_chunks, (13, 12890).
+#: chunk 46,080 points), ``z`` field8 under auto_chunks, (13, 12890).  The
+#: full step stores every variable, ``z`` too, in one-level chunks, which
+#: its I/O servers encode in stages of 8 (2 I/O workers a client).
 CASES = [
     ("o1280-interior", [(1, 262144)], 16),
     ("o1280-edge", [(1, 46080)], 16),
+    ("full-step-stage", [(1, 262144)] * 8, 16),
+    ("full-step-z-stage", [(1, 262144)] * 8, 8),
+    ("full-step-z-edge", [(1, 46080)], 8),
     ("o1280-auto-chunks", [(13, 12890)], 8),
     ("example-chunk", [(60, 90, 2)], 8),
     ("batched", [(2048, 128)] * 4, 8),
